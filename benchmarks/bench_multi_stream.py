@@ -139,7 +139,9 @@ def _frontend_parity(pack, cfg, n_req: int) -> bool:
 
 
 def _sharded_parity() -> dict:
-    env = dict(os.environ)
+    # the child is a CPU leg on forced host devices: pinned to the CPU so it
+    # never reaches for an accelerator this process may already hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = os.pathsep.join(

@@ -4,14 +4,4 @@
 # and the shape-aware block autotuner (autotune.py).
 # ops.py holds the jit'd public wrappers; ref.py the pure-jnp oracles,
 # including the literal bit-plane ACM form of eq. (1).
-from jax.experimental.pallas import tpu as _pltpu
-
-# Version-compat shim: JAX renamed ``pltpu.TPUCompilerParams`` to
-# ``pltpu.CompilerParams``; the installed version may have either.  Every
-# kernel module imports this symbol instead of touching pltpu directly.
-# Defined before the ops import below so the kernel modules can pull it
-# from the partially-initialised package.
-COMPILER_PARAMS = (getattr(_pltpu, "CompilerParams", None)
-                   or _pltpu.TPUCompilerParams)
-
-from . import ops, ref  # noqa: F401,E402
+from . import ops, ref  # noqa: F401

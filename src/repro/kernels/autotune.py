@@ -45,11 +45,13 @@ measurement.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import os
 import threading
-from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
+import warnings
+from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -88,6 +90,52 @@ class BlockConfig:
 _lock = threading.Lock()
 _memory: Dict[str, BlockConfig] = {}
 _disk_loaded_for: Optional[str] = None
+# per thread, the lists open collectors append failed sweep candidates to
+# (a plan resolves its sweeps in the thread that builds it)
+_collectors = threading.local()
+
+
+@contextlib.contextmanager
+def collect_failures(sink: List[str]):
+    """Append to ``sink`` every sweep candidate that fails in this thread
+    while the block is open, as ``"sweep candidate failed: <cache key>
+    <candidate>: <error>"``.  Collectors nest, each open one receiving the
+    failure; with none open, a failure is a ``RuntimeWarning``."""
+    if not hasattr(_collectors, "sinks"):
+        _collectors.sinks = []
+    _collectors.sinks.append(sink)
+    try:
+        yield sink
+    finally:
+        _collectors.sinks.pop()
+
+
+def _sweep(key: str, cands: Sequence, measure: Callable[..., float]
+           ) -> List[float]:
+    """Seconds per candidate (``measure(*cand)`` for tuples).  ``inf``
+    from ``measure`` means "not eligible"; a candidate that raises goes to
+    the open :func:`collect_failures` sinks and is timed ``inf``.  When no
+    candidate ran and at least one raised, the sweep raises: a binding
+    chosen without any measurement would hide the failure."""
+    times, errors = [], []
+    for cand in cands:
+        try:
+            times.append(measure(*cand) if isinstance(cand, tuple)
+                         else measure(cand))
+        except Exception as e:              # noqa: BLE001 — recorded
+            errors.append(f"sweep candidate failed: {key} {cand}: "
+                          f"{type(e).__name__}: {e}".splitlines()[0])
+            times.append(float("inf"))
+    if errors:
+        sinks = getattr(_collectors, "sinks", [])
+        for sink in sinks:
+            sink.extend(errors)
+        if not sinks:
+            warnings.warn("\n".join(errors), RuntimeWarning, stacklevel=2)
+        if all(t == float("inf") for t in times):
+            raise RuntimeError("every sweep candidate failed:\n"
+                               + "\n".join(errors))
+    return times
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -260,16 +308,17 @@ def _resolve_and_cache(key: str, *,
         hit = _memory.get(key)
     if hit is not None:
         return hit
-    if measure is not None:
-        cands = list(candidates())
-        timed = [(measure(c), i) for i, c in enumerate(cands)]
-        best_t, best_i = min(timed)
-        if best_t != float("inf"):
-            cfg = dataclasses.replace(cands[best_i], source="sweep")
-        else:
-            cfg = heuristic()
-    else:
+    if measure is None:
         cfg = heuristic()
+    else:
+        cands = list(candidates())
+        best_t, best_i = min(
+            (t, i) for i, t in enumerate(_sweep(key, cands, measure)))
+        if best_t == float("inf"):
+            # nothing eligible: the heuristic answers, uncached, so it
+            # never masks a later measurement under this backend's key
+            return heuristic()
+        cfg = dataclasses.replace(cands[best_i], source="sweep")
     with _lock:
         _memory[key] = cfg
         if persist:
@@ -328,8 +377,9 @@ def get_block_config(m: int, k: int, n: int, *,
                      persist: bool = True) -> BlockConfig:
     """Resolve blocks for one problem shape (cache → sweep → heuristic).
 
-    ``measure`` runs one candidate and returns seconds (``inf`` = candidate
-    failed to compile/run); when omitted — the interpret/CPU path — the
+    ``measure`` runs one candidate and returns seconds (``inf`` = not
+    eligible; a candidate that raises is recorded, see :func:`_sweep`);
+    when omitted — the interpret/CPU path — the
     heuristic answers directly.  Results land in the memory cache and, when
     ``persist``, the JSON cache, so a warm call never re-measures.
 
@@ -409,8 +459,9 @@ def get_schedule_config(rows: int, k: int, n: int, *,
     ``schedules`` is the bucket's *eligible* set (VMEM-fit and opt-outs
     already applied by the caller, in plans); ``prior`` the dataflow-
     motivated pre-measurement answer.  ``measure(schedule, block_m) ->
-    seconds`` runs the actual kernel on a real backend (``inf`` =
-    candidate failed); without it — the interpret/CPU tier, where timing
+    seconds`` runs the actual kernel on a real backend (``inf`` = not
+    eligible; a candidate that raises is recorded, see :func:`_sweep`);
+    without it — the interpret/CPU tier, where timing
     the interpreter is meaningless — the prior answers, with ``block_m``
     migrated from the old single-entry fused key (``legacy_m`` = the rows
     it was tuned at) or from ``block_m_hint`` rather than re-derived.
@@ -465,8 +516,8 @@ def get_schedule_config(rows: int, k: int, n: int, *,
         sweep_set = tuple(schedules) + tuple(
             s for s in SCHEDULES if s in covered and s not in schedules)
         cands = list(candidate_schedule_blocks(rows, sweep_set))
-        timed = [(measure(s, bm), i) for i, (s, bm) in enumerate(cands)]
-        finite = [(t, i) for t, i in timed if t != float("inf")]
+        finite = [(t, i) for i, t in enumerate(_sweep(key, cands, measure))
+                  if t != float("inf")]
         caller_finite = [(t, i) for t, i in finite
                          if cands[i][0] in schedules]
         if caller_finite:

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import COMPILER_PARAMS
-
 
 def _kernel(w_ref, omega_ref, pen_ref, codes_ref, what_ref):
     w = w_ref[...].astype(jnp.float32)
@@ -86,7 +84,7 @@ def ecl_quant_pallas(w: jax.Array, omega: jax.Array, penalty: jax.Array,
             jax.ShapeDtypeStruct((rp, cp), jnp.uint8),
             jax.ShapeDtypeStruct((rp, cp), jnp.float32),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(wp, omega2, pen2)

@@ -108,7 +108,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import COMPILER_PARAMS, ref
+from . import ref
+from .fantastic4_matmul import decode_tile, lane_dot, trim_padding
 
 # layer dims are padded to this multiple (f32 lane width) before entering
 # the kernel; keeps every in-kernel slice tile-aligned.
@@ -170,19 +171,6 @@ def fused_mlp_fits(shapes: Sequence[Tuple[int, int]], *,
                                 act_dtype, double_buffer) <= budget_bytes
 
 
-def _decode_tile(packed: jax.Array, omega_ref) -> jax.Array:
-    """(kp//2, np) uint8 codes -> (kp, np) f32 W = Σ_i ω_i B_i."""
-    lo = packed & 0xF
-    hi = (packed >> 4) & 0xF
-    codes = jnp.stack([lo, hi], axis=1)
-    codes = codes.reshape(packed.shape[0] * 2, packed.shape[1])
-    w = jnp.zeros(codes.shape, jnp.float32)
-    for i in range(4):
-        bit = ((codes >> i) & 1).astype(jnp.float32)
-        w = w + omega_ref[0, i] * bit
-    return w
-
-
 def _kernel(*refs, activations: Tuple[Optional[str], ...],
             act_dtype: str, n_halves: int):
     n_layers = len(activations)
@@ -197,18 +185,18 @@ def _kernel(*refs, activations: Tuple[Optional[str], ...],
     # group 1 at tick l+1, so the decoded tile stays live for exactly one
     # extra tick (≤2 decoded tiles concurrently) instead of being decoded
     # per group.  The python-level dict is static — the compiler sees one
-    # _decode_tile per layer either way.
+    # decode_tile per layer either way.
     decoded = {}
 
     def apply_layer(cur: jax.Array, l: int, last_use: bool) -> jax.Array:
         packed_ref, omega_ref, alpha1_ref, bias_ref, scale_ref = \
             layer_refs[5 * l:5 * l + 5]
         if l not in decoded:
-            decoded[l] = _decode_tile(packed_ref[...], omega_ref)
+            decoded[l] = decode_tile(packed_ref[...], omega_ref)
         w = decoded[l]
         if last_use:
             del decoded[l]
-        y = jnp.dot(cur, w, preferred_element_type=jnp.float32)
+        y = lane_dot(cur, w)
         y = y * alpha1_ref[...] + bias_ref[...]
         y = ref.apply_activation(y, activations[l])
         if int8_acts:
@@ -326,10 +314,11 @@ def fantastic4_fused_mlp_pallas(
         out_specs=pl.BlockSpec((bm, n_last_p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n_last_p), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, max_width), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)
-    return out[:m, :shapes[-1][1]]
+    return trim_padding(out, m, shapes[-1][1], interpret)
 
 
 # ------------------------------------------------ weight-stationary variant
@@ -420,8 +409,8 @@ def _ws_kernel(x_ref, packed_ref, omega_ref, alpha1_ref, bias_ref, meta_ref,
         act_ref[...] = x_ref[...].astype(jnp.float32)
 
     cur = act_ref[...]
-    w = _decode_tile(packed_ref[0], omega_ref[0])
-    y = jnp.dot(cur, w, preferred_element_type=jnp.float32)
+    w = decode_tile(packed_ref[0], omega_ref[0])
+    y = lane_dot(cur, w)
     y = y * alpha1_ref[0] + bias_ref[0]
     # activation/quantization choices are per-layer *data* (meta operand):
     # the layer id is traced, so the branch cannot be a python conditional.
@@ -493,10 +482,11 @@ def fantastic4_fused_mlp_ws_pallas(
         out_specs=pl.BlockSpec((mp, d), lambda l: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((mp, d), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xp, packed_stack, omega_stack, alpha1_stack, bias_stack, meta_stack)
-    return out[:m, :shapes[-1][1]]
+    return trim_padding(out, m, shapes[-1][1], interpret)
 
 
 # ------------------------------------------- decode-amortized streaming variant
@@ -557,10 +547,10 @@ def _stream_kernel(x_ref, packed_ref, omega_ref, alpha1_ref, bias_ref,
         # inference batch, at its first batch tile, into a scratch that
         # persists across grid steps — every later tile of this layer
         # reuses it (the batch-tiled kernel redoes this per grid step).
-        w_ref[...] = _decode_tile(packed_ref[0], omega_ref[0])
+        w_ref[...] = decode_tile(packed_ref[0], omega_ref[0])
 
     cur = act_ref[pl.ds(i * block_m, block_m), :]
-    y = jnp.dot(cur, w_ref[...], preferred_element_type=jnp.float32)
+    y = lane_dot(cur, w_ref[...])
     y = y * alpha1_ref[0] + bias_ref[0]
     # per-layer activation/quantization choices are data (meta operand),
     # exactly as in the ws kernel — the layer id is traced.
@@ -647,8 +637,8 @@ def fantastic4_fused_mlp_stream_pallas(
         out_shape=jax.ShapeDtypeStruct((mp, d), out_dtype),
         scratch_shapes=[pltpu.VMEM((mp, d), jnp.float32),
                         pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(xp, packed_stack, omega_stack, alpha1_stack, bias_stack, meta_stack)
-    return out[:m, :shapes[-1][1]]
+    return trim_padding(out, m, shapes[-1][1], interpret)

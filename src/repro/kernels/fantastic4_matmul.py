@@ -40,7 +40,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import COMPILER_PARAMS, ref
+from . import ref
+from .autotune import LANE, SUBLANE
+
+
+def decode_tile(packed: jax.Array, omega) -> jax.Array:
+    """(k//2, n) uint8 row-pair codes -> (k, n) f32 W = Σ_i ω_i B_i.
+
+    ``omega`` is indexed ``[0, i]`` (a (1, 4) ref or value).  The codes are
+    widened to int32 before the bit operations: Mosaic has no uint8 ->
+    float32 cast, and the int32 path gives the same bits.
+    """
+    codes32 = packed.astype(jnp.int32)
+    lo = codes32 & 0xF
+    hi = (codes32 >> 4) & 0xF
+    codes = jnp.stack([lo, hi], axis=1)                   # (k//2, 2, n)
+    codes = codes.reshape(packed.shape[0] * 2, packed.shape[1])
+    # the 4-multiplier ACM recombination
+    w = jnp.zeros(codes.shape, jnp.float32)
+    for i in range(4):
+        bit = ((codes >> i) & 1).astype(jnp.float32)
+        w = w + omega[0, i] * bit
+    return w
+
+
+def lane_dot(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` in f32, one 128-column slab of ``w`` at a time.
+
+    Every kernel multiplies through this, so an output column is always
+    the product of the same (rows, K) x (K, 128) dot, whatever width the
+    kernel holds: the schedules keep different widths (the per-layer
+    blocks, each layer's padded width, the stack's widest layer), and
+    XLA's CPU dot rounds a column differently at different widths.  One
+    slab is one pass of the 128-wide MXU.  The precision is full f32: the
+    decoded weights are f32 sums of the ω codebook, which one bf16 MXU
+    pass would round.
+    """
+    return jnp.concatenate(
+        [jnp.dot(x, w[:, j:j + LANE], preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
+         for j in range(0, w.shape[1], LANE)], axis=1)
 
 
 def _kernel(x_ref, w_ref, omega_ref, alpha1_ref, bias_ref, alpha2_ref,
@@ -49,21 +88,9 @@ def _kernel(x_ref, w_ref, omega_ref, alpha1_ref, bias_ref, alpha2_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    packed = w_ref[...]                                   # (bk//2, bn) uint8
-    lo = packed & 0xF
-    hi = (packed >> 4) & 0xF
-    codes = jnp.stack([lo, hi], axis=1)                   # (bk//2, 2, bn)
-    codes = codes.reshape(packed.shape[0] * 2, packed.shape[1])
-
-    # W_tile = Σ_i ω_i B_i  — the 4-multiplier ACM recombination, per tile.
-    w_tile = jnp.zeros(codes.shape, jnp.float32)
-    for i in range(4):
-        bit = ((codes >> i) & 1).astype(jnp.float32)
-        w_tile = w_tile + omega_ref[0, i] * bit
-
+    w_tile = decode_tile(w_ref[...], omega_ref)           # (bk, bn) f32
     x_tile = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jnp.dot(x_tile, w_tile,
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += lane_dot(x_tile, w_tile)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
@@ -73,6 +100,25 @@ def _kernel(x_ref, w_ref, omega_ref, alpha1_ref, bias_ref, alpha2_ref,
         y = ref.apply_activation(y, activation)
         y = y * alpha2_ref[0, 0]
         o_ref[...] = y.astype(o_ref.dtype)
+
+
+def trim_padding(out: jax.Array, m: int, n: int, interpret: bool
+                 ) -> jax.Array:
+    """A kernel's padded result cut to its ``(m, n)`` rows and columns.
+
+    Compiled, the cut is part of the kernel's own program, so a launch is
+    one dispatch.  Interpreted, the padded result is returned and the
+    caller cuts it outside the jit (``ops.unpad``): traced together with
+    the interpreted kernel body, XLA's CPU backend fuses the cut into the
+    dot and rounds some columns differently from the other kernels, which
+    breaks the int8 megakernel-vs-chain parity.  Mosaic's kernel is opaque
+    to XLA, so the compiled cut changes no result.
+    """
+    return out if interpret else out[:m, :n]
+
+
+def _round_up(v: int, mult: int) -> int:
+    return -(-v // mult) * mult
 
 
 def _pad_to(a: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -100,7 +146,15 @@ def fantastic4_matmul_pallas(
     assert k == 2 * k2, (x.shape, packed.shape)
     out_dtype = out_dtype or x.dtype
 
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
+    # pad to whole (8, 128) tiles first, the same padding the fused
+    # megakernel applies, so a layer that fits one block runs the very same
+    # dot shape on both paths (the int8 bit-exactness contract).
+    x = _pad_to(_pad_to(x, 0, SUBLANE), 1, LANE)
+    packed = _pad_to(_pad_to(packed, 0, LANE // 2), 1, LANE)
+    # blocks are whole tiles too: Mosaic refuses any other block shape
+    bm = min(_round_up(block_m, SUBLANE), x.shape[0])
+    bn = min(_round_up(block_n, LANE), packed.shape[1])
+    bk = min(_round_up(block_k, LANE), x.shape[1])
     xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
     wp = _pad_to(_pad_to(packed, 0, bk // 2), 1, bn)
     mp, kp = xp.shape
@@ -126,8 +180,8 @@ def fantastic4_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xp, wp, omega, alpha1, bias, alpha2)
-    return out[:m, :n]
+    return trim_padding(out, m, n, interpret)

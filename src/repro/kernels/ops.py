@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the FantastIC4 Pallas kernels.
 
-On a TPU backend the Pallas kernels run natively; on CPU (this container)
-they execute in ``interpret=True`` mode so every test validates the actual
-kernel body against the pure-jnp oracles in ``ref.py``. ``use_kernel=False``
+On a TPU backend the Pallas kernels run natively; on the CPU they execute
+in ``interpret=True`` mode (``default_interpret``; any other backend is
+refused) so every test validates the actual kernel body against the
+pure-jnp oracles in ``ref.py``. ``use_kernel=False``
 selects the oracle path (used by the models' default serving path on CPU,
 where interpret-mode would be needlessly slow for large layers).
 
@@ -32,42 +33,73 @@ from .fantastic4_fused_mlp import (VMEM_BUDGET_BYTES, build_ws_operands,
 from .fantastic4_matmul import fantastic4_matmul_pallas
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def default_interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: exactly when JAX
+    runs on the CPU.  The TPU compiles them; any other backend has no
+    Pallas path here and is refused rather than quietly interpreted."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for 'tpu' and are interpreted on "
+        f"'cpu'; JAX's default backend is {backend!r}")
+
+
+def unpad(out: jax.Array, m: int, n: int) -> jax.Array:
+    """A kernel result cut to ``(m, n)``: only the interpreter returns it
+    padded; a compiled kernel has cut it in its own program
+    (``fantastic4_matmul.trim_padding``), and no second dispatch runs."""
+    return out if out.shape == (m, n) else out[:m, :n]
 
 
 def _timeit(fn, repeats: int = 3) -> float:
-    """Median wall-clock of ``fn()`` after one warm-up (compile) call."""
-    try:
+    """Median wall-clock of ``fn()`` after one warm-up (compile) call.
+
+    A candidate that fails to compile or run raises; the autotuner's sweep
+    records the error.  A traced result raises too: ``block_until_ready``
+    returns at once on a tracer, so the time would be the trace's."""
+    out = fn()
+    if any(isinstance(a, jax.core.Tracer)
+           for a in jax.tree_util.tree_leaves(out)):
+        raise TypeError("timed sweep called under a trace: resolve the "
+                        "blocks eagerly before jit/shard_map")
+    jax.block_until_ready(out)
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
         jax.block_until_ready(fn())
-        times = []
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn())
-            times.append(time.perf_counter() - t0)
-        return sorted(times)[len(times) // 2]
-    except Exception:
-        return float("inf")               # candidate failed to compile/run
+        times.append(time.perf_counter() - t0)
+    return sorted(times)[len(times) // 2]
 
 
-def _resolve_blocks(m: int, k: int, n: int, *, dtype, interpret: bool,
-                    block_m, block_n, block_k,
-                    measure=None) -> autotune.BlockConfig:
-    """Fill ``None`` blocks from the autotuner; explicit values win.
+def matmul_blocks(m: int, k: int, n: int, *, dtype=jnp.float32,
+                  interpret: bool,
+                  activation: Optional[str] = None) -> autotune.BlockConfig:
+    """The per-layer kernel's blocks for an ``(m, k) @ (k, n)`` layer.
 
+    On the TPU a cache miss times every candidate on zero operands of the
+    layer's shape, so this runs eagerly; callers that trace the kernel
+    (``serving.sharded``) resolve here first and pass the blocks in.
     Interpret-mode answers are keyed under backend "interpret" so they
     never shadow a real backend's timed sweep for the same shape.
     """
-    if None not in (block_m, block_n, block_k):
-        return autotune.BlockConfig(block_m, block_n, block_k,
-                                    source="explicit")
-    cfg = autotune.get_block_config(
-        m, k, n, dtype=str(dtype), fused=False,
+    def _measure(cfg: autotune.BlockConfig) -> float:
+        x = jnp.zeros((m, k), dtype)
+        packed = jnp.zeros((k // 2, n), jnp.uint8)
+        omega = jnp.zeros((4,), jnp.float32)
+        vec = jnp.zeros((n,), jnp.float32)
+        one = jnp.ones((), jnp.float32)
+        return _timeit(lambda: fantastic4_matmul_pallas(
+            x, packed, omega, vec, vec, one, activation=activation,
+            block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
+            interpret=interpret))
+
+    return autotune.get_block_config(
+        m, k, n, dtype=str(jnp.dtype(dtype)), fused=False,
         backend="interpret" if interpret else None,
-        measure=measure if not interpret else None)
-    return autotune.BlockConfig(block_m or cfg.block_m,
-                                block_n or cfg.block_n,
-                                block_k or cfg.block_k, source=cfg.source)
+        measure=None if interpret else _measure)
 
 
 def fantastic4_matmul(x: jax.Array, packed: jax.Array, omega: jax.Array,
@@ -92,27 +124,22 @@ def fantastic4_matmul(x: jax.Array, packed: jax.Array, omega: jax.Array,
         return ref.fantastic4_matmul_ref(
             x, packed, omega, bias=bias, alpha1=alpha1, alpha2=alpha2,
             activation=activation, out_dtype=out_dtype)
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     alpha1 = jnp.ones((n,), jnp.float32) if alpha1 is None else alpha1
     bias = jnp.zeros((n,), jnp.float32) if bias is None else bias
     alpha2 = jnp.ones((), jnp.float32) if alpha2 is None else jnp.asarray(alpha2)
 
-    def _measure(cfg: autotune.BlockConfig) -> float:
-        return _timeit(lambda: fantastic4_matmul_pallas(
-            x, packed, omega, alpha1, bias, alpha2,
-            activation=activation, out_dtype=out_dtype or x.dtype,
-            block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
-            interpret=interpret))
-
-    cfg = _resolve_blocks(x.shape[0], x.shape[1], n, dtype=x.dtype,
-                          interpret=interpret, block_m=block_m,
-                          block_n=block_n, block_k=block_k,
-                          measure=_measure)
-    return fantastic4_matmul_pallas(
+    if None in (block_m, block_n, block_k):
+        cfg = matmul_blocks(x.shape[0], x.shape[1], n, dtype=x.dtype,
+                            interpret=interpret, activation=activation)
+        block_m = block_m or cfg.block_m
+        block_n = block_n or cfg.block_n
+        block_k = block_k or cfg.block_k
+    return unpad(fantastic4_matmul_pallas(
         x, packed, omega, alpha1, bias, alpha2,
         activation=activation, out_dtype=out_dtype or x.dtype,
-        block_m=cfg.block_m, block_n=cfg.block_n, block_k=cfg.block_k,
-        interpret=interpret)
+        block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=interpret), x.shape[0], n)
 
 
 def fantastic4_mlp_chain(x: jax.Array, layers: Sequence[dict], *,
@@ -283,7 +310,7 @@ def fantastic4_mlp_fused(x: jax.Array, layers: Sequence[dict], *,
     assert schedule in autotune.SCHEDULES, schedule
     shapes = tuple(tuple(l["shape"]) for l in layers)
     activations = tuple(l.get("activation") for l in layers)
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     m, k0 = x.shape
     n_last = shapes[-1][1]
 
@@ -312,10 +339,10 @@ def fantastic4_mlp_fused(x: jax.Array, layers: Sequence[dict], *,
             stacked = _ws_stacked_operands(
                 layers, act_dtype, act_scales if act_dtype == "int8"
                 else None)
-            return fantastic4_fused_mlp_ws_pallas(
+            return unpad(fantastic4_fused_mlp_ws_pallas(
                 x, *stacked, shapes=shapes, activations=activations,
                 out_dtype=out_dtype or x.dtype, interpret=interpret,
-                act_dtype=act_dtype)
+                act_dtype=act_dtype), m, n_last)
         # over-budget even per layer: same per-layer-chain fallback as the
         # batch-tiled schedule below.
         return _chain_fallback(True)
@@ -328,10 +355,10 @@ def fantastic4_mlp_fused(x: jax.Array, layers: Sequence[dict], *,
             stacked = _ws_stacked_operands(
                 layers, act_dtype, act_scales if act_dtype == "int8"
                 else None)
-            return fantastic4_fused_mlp_stream_pallas(
+            return unpad(fantastic4_fused_mlp_stream_pallas(
                 x, *stacked, shapes=shapes, activations=activations,
                 out_dtype=out_dtype or x.dtype, block_m=bm,
-                interpret=interpret, act_dtype=act_dtype)
+                interpret=interpret, act_dtype=act_dtype), m, n_last)
         return _chain_fallback(True)
 
     def _measure(cfg: autotune.BlockConfig) -> float:
@@ -340,7 +367,7 @@ def fantastic4_mlp_fused(x: jax.Array, layers: Sequence[dict], *,
     def _call_fused(bm: int) -> jax.Array:
         # NB: no jnp.asarray here — pack entries are already device arrays
         # and per-array asarray dominates the wrapper's dispatch cost.
-        return fantastic4_fused_mlp_pallas(
+        return unpad(fantastic4_fused_mlp_pallas(
             x,
             tuple(l["packed"] for l in layers),
             tuple(l["omega"] for l in layers),
@@ -350,7 +377,7 @@ def fantastic4_mlp_fused(x: jax.Array, layers: Sequence[dict], *,
             shapes=shapes, activations=activations,
             out_dtype=out_dtype or x.dtype, block_m=bm,
             interpret=interpret, act_dtype=act_dtype,
-            double_buffer=schedule == "db")
+            double_buffer=schedule == "db"), m, n_last)
 
     # fits check first (conservatively at the largest candidate block_m):
     # an over-budget stack must not pay for a fused-candidate sweep whose
@@ -388,7 +415,7 @@ def ecl_quant(w: jax.Array, omega: jax.Array, penalty: jax.Array,
     """
     if not use_kernel:
         return ref.ecl_quant_ref(w, omega, penalty)
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     squeeze = w.ndim == 1
     w2 = w[None, :] if squeeze else w.reshape(w.shape[0], -1)
     if block_r is None or block_c is None:

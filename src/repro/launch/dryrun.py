@@ -55,9 +55,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
 
-        from ..compat import cost_analysis
         mem = compiled.memory_analysis()
-        cost = cost_analysis(compiled)
+        cost = compiled.cost_analysis() or {}
         from . import hlo_analysis
         hlo = hlo_analysis.analyze(compiled.as_text())
         n_dev = int(mesh.devices.size)

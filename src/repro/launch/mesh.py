@@ -20,7 +20,7 @@ import numpy as np
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def fit_mesh(n_devices: Optional[int] = None, *,
@@ -54,15 +54,19 @@ def fit_mesh(n_devices: Optional[int] = None, *,
         model = 1
         while n % (model * 2) == 0 and (model * 2) ** 2 <= n:
             model *= 2
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes)
+    """A mesh whose axes are all ``Auto``: the compiler places what the
+    partition specs leave open, and eager slicing of a sharded result (a
+    served batch's real rows) reshards instead of raising."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh() -> jax.sharding.Mesh:
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def describe(mesh: jax.sharding.Mesh) -> dict:

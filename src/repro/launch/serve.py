@@ -52,6 +52,9 @@ megakernel-backed FFN plan set per transformer block, prefill and decode
 steps as wire rows through a ``ServingFrontend`` — and the engine's decode
 tokens are asserted bit-identical to the program's direct ``generate``
 loop.  Dense-attention archs only (the program's contract).
+
+Run as a program, the launcher keeps JAX's compile cache and the block
+autotuner's JSON at fixed places (``launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -602,4 +605,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from . import compile_cache
+    print(f"compile cache: {compile_cache.enable()}")
     main()

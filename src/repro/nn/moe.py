@@ -36,8 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 from .layers import linear, linear_init, subtree
 from .module import QuantCtx, materialize
 
@@ -268,7 +266,7 @@ def moe_apply_ep(p: dict, q_state: Any, x: jax.Array, ctx: QuantCtx, *,
             for k in ("gate", "up", "down")]
 
     tok_spec = P(all_axes, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P(None),
                   P(expert_axis, None, None), P(expert_axis, None, None),
@@ -343,7 +341,7 @@ def moe_apply_tp(p: dict, q_state: Any, x: jax.Array, ctx: QuantCtx, *,
         return y, jax.lax.pmean(aux, data_axes)
 
     tok_spec = P(data_axes, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P(None),
                   P(None, None, expert_axis), P(None, None, expert_axis),

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class GradCompressCfg:
@@ -72,7 +70,7 @@ def compress_grads(grads: Any, err: Any, cfg: GradCompressCfg, *,
                     return qsum.astype(jnp.float32) * s / n_dev, q, s
                 # grads enter replicated over data axes (pjit already
                 # reduced them); production wiring would psum here instead.
-                deq, q, s = shard_map(
+                deq, q, s = jax.shard_map(
                     allreduce_q, mesh=mesh,
                     in_specs=P(*[None] * gf.ndim),
                     out_specs=(P(*[None] * gf.ndim),

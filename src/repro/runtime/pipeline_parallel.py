@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def stage_split(stacked_params: Any, n_stages: int) -> Any:
     """(L, ...) stacked layer params -> (S, L//S, ...) stage-major."""
@@ -94,8 +92,8 @@ def pipeline_apply(layer_fn: Callable, stage_params: Any, x: jax.Array, *,
 
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 P(*([None] * micro.ndim)))
-    out = shard_map(stage_body, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(), check_vma=False)(stage_params, micro)
+    out = jax.shard_map(stage_body, mesh=mesh, in_specs=in_specs,
+                        out_specs=P(), check_vma=False)(stage_params, micro)
     return out.reshape(b, *x.shape[1:])
 
 

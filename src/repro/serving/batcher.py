@@ -102,6 +102,11 @@ class Completion:
     batched_rows: int         # real rows sharing the launch
 
 
+def _device_names(a) -> Tuple[str, ...]:
+    return tuple(sorted(str(d) for d in a.devices())) \
+        if hasattr(a, "devices") else ()
+
+
 @dataclasses.dataclass
 class Taken:
     """One coalesced bucket popped from the queue but not yet launched —
@@ -111,6 +116,9 @@ class Taken:
     consumes them, so a failed launch can requeue them intact."""
     requests: List[_Pending]
     rows: int
+    # where the launch's batch and its result lived, as device names
+    # (set by execute)
+    devices: Optional[Tuple[Tuple[str, ...], Tuple[str, ...]]] = None
 
 
 class MicroBatcher:
@@ -357,12 +365,14 @@ class MicroBatcher:
                 else contextlib.nullcontext()
             with ctx:
                 if bucket is None:
-                    y = self.plan.run(jnp.asarray(xb))  # oversized: exact
-                    bucket = rows
+                    run, bucket = self.plan.run, rows   # oversized: exact
                 else:
                     if padded:
                         xb = np.pad(xb, ((0, padded), (0, 0)))
-                    y = self.plan.entry(bucket)(jnp.asarray(xb))
+                    run = self.plan.entry(bucket)
+                xd = jnp.asarray(xb)
+                y = run(xd)
+                t.devices = (_device_names(xd), _device_names(y))
                 y = np.asarray(jax.block_until_ready(y))
         except BaseException:
             # a failed launch loses NOTHING: requests are host-side numpy

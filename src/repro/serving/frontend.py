@@ -421,8 +421,16 @@ class ServingFrontend:
                       "streams": [{"launches": 0, "launch_failures": 0,
                                    "busy_s": 0.0, "quarantined": False,
                                    "last_launch_s": None,
-                                   "inflight": False, "stalled": False}
-                                  for _ in range(streams)]}
+                                   "inflight": False, "stalled": False,
+                                   # where the stream launches (None: the
+                                   # default device), and where its
+                                   # launches' batches and results were
+                                   # (recorded by the stream workers)
+                                   "device": None if dev is None
+                                   else str(dev),
+                                   "batch_devices": [],
+                                   "result_devices": []}
+                                  for dev in self._devices]}
 
     def _model_stats(self, model_id: str) -> dict:
         # lazy: models may be registered through self.register OR straight
@@ -573,7 +581,8 @@ class ServingFrontend:
         with self._cond:
             if self._error is not None:
                 raise RuntimeError(
-                    "frontend dispatch thread died") from self._error
+                    f"frontend dispatch thread died: {self._error!r}"
+                ) from self._error
             # quarantine check precedes the registry lookup: a
             # quarantined model is *unregistered* (lifecycle fix) yet
             # must keep rejecting with the typed reason, not "unknown
@@ -1089,6 +1098,9 @@ class ServingFrontend:
                 ss = self.stats["streams"][idx]
                 ss["launches"] += 1
                 ss["busy_s"] += dt
+                for key, names in zip(("batch_devices", "result_devices"),
+                                      taken.devices or ((), ())):
+                    ss[key] = sorted(set(ss[key]) | set(names))
                 for c in done:
                     fut = self._futures.pop((model_id, c.rid), None)
                     if fut is not None and not fut.cancelled():

@@ -221,7 +221,7 @@ class ExecutionPlan:
         self.requested_mode = mode
         self.act_dtype = act_dtype
         self.requested_double_buffer = double_buffer
-        self.interpret = (jax.default_backend() != "tpu"
+        self.interpret = (kops.default_interpret()
                           if interpret is None else interpret)
         self.vmem_budget_bytes = vmem_budget_bytes
         self.notes: List[str] = []
@@ -333,13 +333,16 @@ class ExecutionPlan:
                         act_dtype=act_dtype, act_scales=self.act_scales,
                         vmem_budget_bytes=vmem_budget_bytes))
 
-                cfg = autotune.get_block_config(
-                    max_bucket, self.d_in, self.d_out,
-                    dtype="float32", fused=True,
-                    backend="interpret" if self.interpret else None,
-                    act_dtype=act_dtype,
-                    extra=self._stack_extra,
-                    measure=None if self.interpret else _measure)
+                # a sweep candidate that fails to compile is reported in
+                # the notes with its error, here and per bucket below
+                with autotune.collect_failures(self.notes):
+                    cfg = autotune.get_block_config(
+                        max_bucket, self.d_in, self.d_out,
+                        dtype="float32", fused=True,
+                        backend="interpret" if self.interpret else None,
+                        act_dtype=act_dtype,
+                        extra=self._stack_extra,
+                        measure=None if self.interpret else _measure)
                 self.block_m = cfg.block_m
                 self.block_source = cfg.source
             else:
@@ -363,8 +366,9 @@ class ExecutionPlan:
                 self.buckets[b] = BucketPlan(b, mode)
             self.default_path = mode
         else:
-            for b in self.bucket_sizes:
-                self.buckets[b] = self._bind_bucket(b, max_bucket)
+            with autotune.collect_failures(self.notes):
+                for b in self.bucket_sizes:
+                    self.buckets[b] = self._bind_bucket(b, max_bucket)
             # overflow batches (past the largest bucket) run at exact size:
             # batch-tiled (double-buffered when requested and it fits) or
             # the per-layer chain when the whole stack can't reside.

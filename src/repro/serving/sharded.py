@@ -40,13 +40,13 @@ the gather — correct everywhere, scale-out where the pack allows it.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..kernels import ops as kops
 from ..runtime.sharding import Rules, serving_pack_specs
 
@@ -74,7 +74,8 @@ class ShardedStack:
         self.layers = pack["layers"]
         self.act_dtype = act_dtype
         self.act_scales = list(act_scales) if act_scales else None
-        self.interpret = interpret
+        self.interpret = (kops.default_interpret() if interpret is None
+                          else interpret)
         self.use_kernel = use_kernel
         axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         self.dp = int(axis_sizes.get("data", 1))
@@ -103,7 +104,26 @@ class ShardedStack:
 
     # ----------------------------------------------------------- body
 
-    def _stack_body(self, x: jax.Array, operands) -> jax.Array:
+    def _layer_blocks(self, m: int) -> Tuple[Optional[tuple], ...]:
+        """Per-layer kernel blocks for an ``m``-row batch, resolved eagerly
+        (the TPU sweep times real kernels, which it cannot do on the
+        tracers inside ``shard_map``).  Each layer takes the blocks of its
+        *unsharded* ``(m, K, N)`` problem — the per-layer chain's own cache
+        entry — so every column is summed over the same K blocks as in
+        the chain; ``block_n`` is clamped to the shard's width by the
+        kernel."""
+        if not self.use_kernel:
+            return (None,) * len(self.layers)
+        out = []
+        for layer in self.layers:
+            k, n = layer["shape"]
+            cfg = kops.matmul_blocks(m, k + k % 2, n,
+                                     interpret=self.interpret,
+                                     activation=layer.get("activation"))
+            out.append(cfg.as_tuple())
+        return tuple(out)
+
+    def _stack_body(self, x: jax.Array, operands, blocks) -> jax.Array:
         """Per-shard stack: the per-layer chain with column-local matmuls
         and a tiled gather after each split layer.  Mirrors
         ``fantastic4_mlp_chain`` / ``fantastic4_mlp_chain_int8``
@@ -114,6 +134,7 @@ class ShardedStack:
         in_scale = 1.0
         for i, (layer, ops_i) in enumerate(zip(self.layers, operands)):
             packed, omega, alpha1, bias, alpha2 = ops_i
+            bm, bn, bk = blocks[i] or (None, None, None)
             if layer["shape"][0] % 2:
                 # odd K: the pack carries one zero code row — mirror on x
                 xq = jnp.pad(xq, ((0, 0), (0, 1)))
@@ -123,7 +144,8 @@ class ShardedStack:
             y = kops.fantastic4_matmul(
                 xq, packed, omega, bias=bias, alpha1=alpha1,
                 alpha2=alpha2, activation=layer.get("activation"),
-                use_kernel=self.use_kernel, interpret=self.interpret)
+                use_kernel=self.use_kernel, interpret=self.interpret,
+                block_m=bm, block_n=bn, block_k=bk)
             if self.col_sharded[i]:
                 y = jax.lax.all_gather(y, "model", axis=1, tiled=True)
             if int8 and i < n - 1:
@@ -143,10 +165,14 @@ class ShardedStack:
             # indivisible batch replicates (every device computes every
             # row — correct, not scaled) instead of failing.
             xspec = P("data", None) if m % self.dp == 0 else P(None, None)
-            mapped = shard_map(
-                self._stack_body, mesh=self.mesh,
+            body = functools.partial(self._stack_body,
+                                     blocks=self._layer_blocks(m))
+            mapped = jax.shard_map(
+                body, mesh=self.mesh,
                 in_specs=(xspec, self._operand_specs),
-                out_specs=xspec)
+                out_specs=xspec,
+                # Pallas outputs carry no varying-axes annotation
+                check_vma=False)
             fn = jax.jit(mapped)
             self._fns[(m, d)] = fn
         return fn

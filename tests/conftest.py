@@ -22,9 +22,11 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
     """Run a python snippet in a subprocess with N fake host devices.
 
     Multi-device tests must not pollute this process's jax device state
-    (smoke tests and benches see 1 device, per the assignment).
+    (smoke tests and benches see 1 device, per the assignment).  The child
+    is pinned to the CPU: an accelerator belongs to one process, and this
+    one may already hold it.
     """
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run([sys.executable, "-c", code], env=env,

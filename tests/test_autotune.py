@@ -502,3 +502,95 @@ def test_ops_autotuned_blocks_match_ref(tuner_cache):
                                 interpret=True, out_dtype=jnp.float32)
     y_r = ref.fantastic4_matmul_ref(x, packed, omega, out_dtype=jnp.float32)
     np.testing.assert_allclose(y_k, y_r, atol=1e-4, rtol=1e-4)
+
+
+def test_sweep_records_a_failing_candidate_and_binds_the_rest(tuner_cache):
+    """A candidate that raises is recorded with its error, never dropped
+    in silence, and the sweep binds the best of the others."""
+    def measure(s, bm):
+        if s == "db":
+            raise NotImplementedError("Unsupported cast: uint8 -> float32")
+        return 1.0 if s == "batch_tiled" else 2.0
+
+    with autotune.collect_failures([]) as new:
+        cfg = autotune.get_schedule_config(
+            32, 64, 64, schedules=("batch_tiled", "db", "ws"),
+            prior="batch_tiled", backend="tpu", stack="s", measure=measure)
+    assert cfg.schedule == "batch_tiled" and cfg.source == "sweep"
+    assert new and all(m.startswith("sweep candidate failed")
+                       and "'db'" in m and "Unsupported cast" in m
+                       for m in new)
+
+
+def test_sweep_failures_stay_with_their_thread(tuner_cache):
+    """Each collector sees the failures of the sweeps its own thread ran,
+    so two plans built at once never get each other's notes; a failure
+    with no collector open is a warning."""
+    import threading
+
+    def failing(tag):
+        def measure(cfg):
+            if cfg.block_m == 32:
+                raise RuntimeError(f"refused in {tag}")
+            return 1.0
+        return measure
+
+    got = {}
+
+    def build(tag, k):
+        with autotune.collect_failures([]) as sink:
+            autotune.get_block_config(64, k, 64, backend="tpu",
+                                      measure=failing(tag))
+        got[tag] = sink
+
+    threads = [threading.Thread(target=build, args=(t, k))
+               for t, k in (("a", 64), ("b", 128))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for tag in ("a", "b"):
+        assert got[tag] and all(f"refused in {tag}" in m for m in got[tag])
+    with pytest.warns(RuntimeWarning, match="refused in c"):
+        autotune.get_block_config(64, 256, 64, backend="tpu",
+                                  measure=failing("c"))
+
+
+def test_sweep_raises_when_every_candidate_fails(tuner_cache):
+    """No measurement at all is an error, not a silent heuristic binding
+    persisted under the real backend's key."""
+    def measure(cfg):
+        raise RuntimeError("Mosaic refused the kernel")
+
+    with pytest.raises(RuntimeError, match="every sweep candidate failed"):
+        autotune.get_block_config(16, 64, 64, backend="tpu",
+                                  measure=measure)
+    assert not tuner_cache.exists()
+
+
+def test_timeit_refuses_to_time_a_trace():
+    """block_until_ready returns at once on a tracer, so a sweep under jit
+    would time the trace: it raises instead."""
+    import jax
+
+    @jax.jit
+    def traced(x):
+        ops._timeit(lambda: x + 1.0)
+        return x
+
+    with pytest.raises(TypeError, match="under a trace"):
+        traced(jnp.ones(()))
+
+
+@pytest.mark.parametrize("backend,expected", [("cpu", True), ("tpu", False),
+                                              ("gpu", None)])
+def test_interpret_only_on_cpu(monkeypatch, backend, expected):
+    """Interpret mode is chosen on the CPU alone; a backend with no Pallas
+    path here is refused instead of quietly interpreted."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expected is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops.default_interpret()
+    else:
+        assert ops.default_interpret() is expected

@@ -10,12 +10,13 @@ import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.core import qat
+from repro.launch.mesh import make_mesh
 from repro.nn import transformer as T
 from repro.nn.module import QuantCtx
 
 cfg = get_config("smollm-360m").smoke()   # 4 heads % model-axis 4 == 0?  -> force reshard
 cfg = dataclasses.replace(cfg, n_heads=3, n_kv=3, head_dim=16, d_model=48)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ctx = QuantCtx(quant=False, compute_dtype=jnp.float32)
 key = jax.random.PRNGKey(0)
 p = T.lm_init(key, cfg)

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis
 from repro.launch import hlo_analysis as H
 
 
@@ -27,7 +26,7 @@ def test_loop_free_matches_xla():
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     c = jax.jit(g).lower(a, a).compile()
     r = H.analyze(c.as_text())
-    xla = cost_analysis(c)
+    xla = c.cost_analysis()
     assert abs(r["flops"] - xla["flops"]) / xla["flops"] < 0.02
     assert abs(r["bytes"] - xla["bytes accessed"]) / xla["bytes accessed"] < 0.2
 

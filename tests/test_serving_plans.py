@@ -366,3 +366,28 @@ def test_compat_wrappers_flow_through_plans():
     np.testing.assert_array_equal(
         np.asarray(M.mlp_serve(pack, x, interpret=True)),
         np.asarray(plan.run(x)))
+
+
+def test_failed_sweep_candidate_is_a_plan_note(monkeypatch):
+    """On a backend that sweeps, a (schedule, block_m) candidate that fails
+    to compile is reported in the plan's notes with its error and never
+    bound; the other candidates still bind."""
+    from repro.kernels import autotune
+    real = ops.fantastic4_mlp_fused
+
+    def fake_fused(x, layers, *, schedule=None, **kw):
+        if schedule == "db":
+            raise NotImplementedError("Unsupported cast: uint8 -> float32")
+        return real(x, layers, schedule=schedule, **{**kw,
+                                                     "interpret": True})
+
+    monkeypatch.setattr(ops, "fantastic4_mlp_fused", fake_fused)
+    with autotune.collect_failures([]) as seen:
+        plan = serving.build_plan(_rand_pack(DIMS), mode="fused",
+                                  interpret=False, max_bucket=32)
+    d = plan.describe()
+    failed = [n for n in d["notes"] if n.startswith("sweep candidate failed")]
+    assert failed and all("Unsupported cast" in n for n in failed)
+    assert failed == seen
+    assert "fused_db" not in d["bucket_paths"].values()
+    assert {d["bucket_sources"][b] for b in d["bucket_sizes"]} == {"sweep"}

@@ -5,6 +5,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -57,6 +58,28 @@ def test_sharded_plan_single_device_bit_identical():
                                       np.asarray(shp.run(x)))
     desc = shp.describe()["sharding"]
     assert desc["n_devices"] == 1
+
+
+def test_sharded_plan_resolves_blocks_before_tracing(monkeypatch):
+    """The per-layer blocks are resolved eagerly, for the unsharded layer
+    shapes, and reach the shard_map body as explicit values: a TPU sweep
+    must time real kernels, not the tracers inside the program."""
+    import jax
+    from repro.kernels import ops as kops
+    calls = []
+    real = kops.matmul_blocks
+
+    def spy(m, k, n, **kw):
+        calls.append((m, k, n, isinstance(jnp.zeros(()), jax.core.Tracer)))
+        return real(m, k, n, **kw)
+
+    monkeypatch.setattr(kops, "matmul_blocks", spy)
+    pack = _rand_pack(DIMS)
+    shp = serving.build_plan(pack, mode="sharded", mesh=fit_mesh())
+    shp.run(jnp.zeros((5, DIMS[0]), jnp.float32))
+    assert calls and not any(traced for *_, traced in calls)
+    assert {(k, n) for _, k, n, _ in calls} == {
+        (k + k % 2, n) for k, n in zip(DIMS[:-1], DIMS[1:])}
 
 
 def test_sharded_plan_requires_mesh():
@@ -184,11 +207,64 @@ def test_frontend_streams_parity_and_stats():
     for x, out in zip(xs, outs):
         assert not isinstance(out, serving.Rejected), out
         assert out.stream in (0, 1)
-        np.testing.assert_array_equal(out.y, np.asarray(plan.run(x)))
+        # padding parity: the request alone through the bucket that served
+        # it, bit for bit (a mis-scatter shows here)
+        alone = np.zeros((out.bucket, DIMS[0]), np.float32)
+        alone[:len(x)] = x
+        np.testing.assert_array_equal(
+            out.y, np.asarray(plan.entry(out.bucket)(alone))[:len(x)])
+        # and the direct run at the request's own size, to f32 rounding:
+        # XLA's CPU dot rounds a row differently at another row count
+        direct = np.asarray(plan.run(x))
+        np.testing.assert_allclose(out.y, direct, rtol=1e-6,
+                                   atol=1e-6 * np.abs(direct).max())
     st = fe.stats
     assert len(st["streams"]) == 2
     assert sum(s["launches"] for s in st["streams"]) == st["launches"]
     assert st["by_model"]["m"]["requests"] == len(xs)
+    here = [str(jax.devices()[0])]
+    for s in st["streams"]:
+        if s["launches"]:
+            assert s["batch_devices"] == s["result_devices"] == here, s
+
+
+def test_frontend_streams_record_their_devices():
+    """On a 4-device host each stream worker launches on its own device
+    and records where its batches and results lived."""
+    out = run_with_devices("""
+import jax, jax.numpy as jnp, numpy as np
+from repro import serving
+from repro.core import bitplanes as bp
+
+rng = np.random.default_rng(0)
+k, n = 16, 8
+layer = {"packed": bp.pack_codes_rows(jnp.asarray(
+             rng.integers(0, 16, size=(k, n)).astype(np.uint8))),
+         "omega": jnp.asarray(rng.normal(size=4), jnp.float32),
+         "alpha1": jnp.ones((n,), jnp.float32),
+         "bias": jnp.zeros((n,), jnp.float32),
+         "alpha2": jnp.asarray(np.float32(1.0)),
+         "shape": (k, n), "activation": None}
+plan = serving.build_plan({"layers": [layer], "act_bits": None},
+                          mode="oracle")
+fe = serving.ServingFrontend(streams=4)
+fe.register("m", plan, max_delay=1e-3)
+with fe:
+    futs = [fe.submit("m", rng.normal(size=(1, k)).astype(np.float32))
+            for _ in range(64)]
+    for f in futs:
+        f.result(60.0)
+devs = [str(d) for d in jax.devices()]
+used = 0
+for s in fe.stats["streams"]:
+    assert s["device"] in devs, s
+    if s["launches"]:
+        used += 1
+        assert s["batch_devices"] == s["result_devices"] == [s["device"]], s
+assert used >= 1
+print("stream-devices-ok", used)
+""", n_devices=4)
+    assert "stream-devices-ok" in out
 
 
 def test_frontend_single_stream_has_no_stream_workers():
