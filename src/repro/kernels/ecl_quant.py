@@ -87,5 +87,6 @@ def ecl_quant_pallas(w: jax.Array, omega: jax.Array, penalty: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="ecl_quant_pallas",
     )(wp, omega2, pen2)
     return codes[:r, :c], what[:r, :c]

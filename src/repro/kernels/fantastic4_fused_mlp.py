@@ -317,6 +317,7 @@ def fantastic4_fused_mlp_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="fantastic4_fused_mlp_pallas",
     )(*operands)
     return trim_padding(out, m, shapes[-1][1], interpret)
 
@@ -485,6 +486,7 @@ def fantastic4_fused_mlp_ws_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fantastic4_fused_mlp_ws_pallas",
     )(xp, packed_stack, omega_stack, alpha1_stack, bias_stack, meta_stack)
     return trim_padding(out, m, shapes[-1][1], interpret)
 
@@ -640,5 +642,6 @@ def fantastic4_fused_mlp_stream_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="fantastic4_fused_mlp_stream_pallas",
     )(xp, packed_stack, omega_stack, alpha1_stack, bias_stack, meta_stack)
     return trim_padding(out, m, shapes[-1][1], interpret)

@@ -183,5 +183,6 @@ def fantastic4_matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="fantastic4_matmul_pallas",
     )(xp, wp, omega, alpha1, bias, alpha2)
     return trim_padding(out, m, n, interpret)

@@ -78,6 +78,7 @@ from typing import Callable, Deque, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .slo import (REJECT_QUEUE_FULL, AdmissionController, Rejected,
                   SLOTier, resolve_tier)
@@ -117,7 +118,7 @@ class Taken:
     requests: List[_Pending]
     rows: int
     # where the launch's batch and its result lived, as device names
-    # (set by execute)
+    # (set by execute when asked to record them)
     devices: Optional[Tuple[Tuple[str, ...], Tuple[str, ...]]] = None
 
 
@@ -188,7 +189,8 @@ class MicroBatcher:
                       "bucket_hist": {}, "compute_s": 0.0,
                       "wall_compute_s": 0.0, "rejected_full": 0,
                       "shed_deadline": 0, "rejected_rows": 0,
-                      "launch_failures": 0}
+                      "launch_failures": 0, "flushed_requests": 0,
+                      "queue_wait_s": 0.0}
 
     def _now(self, now: Optional[float]) -> float:
         if now is not None:
@@ -312,9 +314,10 @@ class MicroBatcher:
         *takes* (so it can cost the bucket and pick the least-loaded
         stream) and the chosen stream worker *executes*.  A taken bucket
         the caller abandons can be returned via :meth:`requeue`."""
-        self._now(now)
-        with self._lock:
-            taken = self._take()
+        with TraceAnnotation("serving.take"):
+            self._now(now)
+            with self._lock:
+                taken = self._take()
         if not taken:
             return None
         return Taken(taken, sum(p.rows for p in taken))
@@ -339,7 +342,7 @@ class MicroBatcher:
             return [], 0, 0.0
         return self.execute(t)
 
-    def execute(self, t: Taken, *, device=None
+    def execute(self, t: Taken, *, device=None, record_devices: bool = False
                 ) -> Tuple[List[Completion], int, float]:
         """Launch one taken bucket (the execution half of
         :meth:`run_one`).  ``device`` routes the launch to a specific
@@ -348,8 +351,17 @@ class MicroBatcher:
         executable and the compute really lands on their device; on the
         single-device interpret host it is a no-op and streams degrade
         to threads sharing the device.  A failed launch requeues the
-        taken requests at the queue head, exactly as before the split."""
+        taken requests at the queue head, exactly as before the split.
+        ``record_devices`` sets ``t.devices`` (where the batch and the
+        result lived) for a caller that reads it.
+
+        Each phase is a profiler span (``serving.coalesce``, ``.h2d``,
+        ``.enqueue``, ``.sync``, ``.d2h``), and the launch adds to
+        ``stats["queue_wait_s"]`` (launch start minus arrival, summed
+        over its requests; live clock only, as ``compute_s``) and
+        ``stats["flushed_requests"]``."""
         taken, rows = t.requests, t.rows
+        start = self.clock() if self._live_clock else None
         bucket = self.plan.bucket_for(rows)
         padded = (bucket or rows) - rows
         # coalesce/pad/scatter run host-side in numpy: every distinct
@@ -357,8 +369,11 @@ class MicroBatcher:
         # tiny concat/pad/slice XLA programs, and under ragged live
         # traffic those combos never stop being new — the bucket entry is
         # the only device program a launch should ever wait on.
-        xb = np.concatenate([p.x for p in taken], axis=0) \
-            if len(taken) > 1 else taken[0].x
+        with TraceAnnotation("serving.coalesce"):
+            xb = np.concatenate([p.x for p in taken], axis=0) \
+                if len(taken) > 1 else taken[0].x
+            if bucket is not None and padded:
+                xb = np.pad(xb, ((0, padded), (0, 0)))
         t0 = time.perf_counter()
         try:
             ctx = jax.default_device(device) if device is not None \
@@ -367,13 +382,17 @@ class MicroBatcher:
                 if bucket is None:
                     run, bucket = self.plan.run, rows   # oversized: exact
                 else:
-                    if padded:
-                        xb = np.pad(xb, ((0, padded), (0, 0)))
                     run = self.plan.entry(bucket)
-                xd = jnp.asarray(xb)
-                y = run(xd)
-                t.devices = (_device_names(xd), _device_names(y))
-                y = np.asarray(jax.block_until_ready(y))
+                with TraceAnnotation("serving.h2d"):
+                    xd = jnp.asarray(xb)
+                with TraceAnnotation("serving.enqueue"):
+                    y = run(xd)
+                if record_devices:
+                    t.devices = (_device_names(xd), _device_names(y))
+                with TraceAnnotation("serving.sync"):
+                    jax.block_until_ready(y)
+                with TraceAnnotation("serving.d2h"):
+                    y = np.asarray(y)
         except BaseException:
             # a failed launch loses NOTHING: requests are host-side numpy
             # until the kernel consumes them, so put the taken batch back
@@ -415,9 +434,11 @@ class MicroBatcher:
             st["flushed_rows"] += rows
             st["padded_rows"] += padded
             st["bucket_hist"][bucket] = st["bucket_hist"].get(bucket, 0) + 1
+            st["flushed_requests"] += len(taken)
             st["wall_compute_s"] += dt
             if self._live_clock:
                 st["compute_s"] += dt
+                st["queue_wait_s"] += sum(start - p.arrival for p in taken)
         return out, bucket, dt
 
     def pump(self, now: Optional[float] = None,

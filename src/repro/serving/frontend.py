@@ -128,6 +128,19 @@ degrades the fleet by 1/N instead of killing it.  Failures that follow
 the model across streams still walk the model ladder (retry → chain
 fallback → model quarantine) exactly as before.  ``streams=1``
 (default) is byte-for-byte the single-stream driver above.
+
+Profiler spans
+--------------
+
+The serving path marks its phases with ``jax.profiler.TraceAnnotation``
+spans, which cost well under a microsecond each when no trace is active:
+``serving.submit`` (the caller's thread), ``serving.wait`` (the dispatch
+thread with nothing due), and per launch ``serving.launch`` (metadata
+``bucket``, ``rows``, ``requests``) holding ``serving.take``,
+``.coalesce``, ``.h2d``, ``.enqueue``, ``.sync``, ``.d2h`` (from
+:meth:`MicroBatcher.execute`) and ``serving.scatter`` (resolving the
+futures, done callbacks included).  ``ExecutionPlan.run`` is
+``serving.plan_run``.
 """
 from __future__ import annotations
 
@@ -140,6 +153,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..runtime.integrity import (GuardedPlan, IntegrityError,
                                  IntegrityPolicy, unwrap_chain)
@@ -577,6 +591,10 @@ class ServingFrontend:
         that ``await``/``result()`` uniformly see every outcome.  Invalid
         requests (bad shape, unknown model) still raise synchronously:
         those are caller bugs, not load conditions."""
+        with TraceAnnotation("serving.submit"):
+            return self._submit(model_id, x)
+
+    def _submit(self, model_id: str, x) -> concurrent.futures.Future:
         fut: concurrent.futures.Future = concurrent.futures.Future()
         with self._cond:
             if self._error is not None:
@@ -957,34 +975,40 @@ class ServingFrontend:
                     pick = self._pick(now)
                     if pick is None:
                         deadline = self.registry.next_deadline()
-                        self._cond.wait(
-                            None if deadline is None
-                            else max(deadline - now, 0.0))
+                        with TraceAnnotation("serving.wait"):
+                            self._cond.wait(
+                                None if deadline is None
+                                else max(deadline - now, 0.0))
                         continue
             model_id, batcher = pick
             with self._cond:
                 ss = self.stats["streams"][0]
                 ss["last_launch_s"] = self.clock()   # watchdog heartbeat
                 ss["inflight"] = True
-            try:
-                done, _bucket, _dt = batcher.run_one()
-            except Exception as exc:           # noqa: BLE001
-                self._degrade(model_id, batcher, exc)
-                continue
-            finally:
-                with self._cond:
-                    ss["inflight"] = False
-            finish = self.clock()
-            with self._cond:
-                self._fail_streak.pop(model_id, None)
-                self.stats["launches"] += 1
-                self._model_stats(model_id)["launches"] += 1
-                for c in done:
-                    fut = self._futures.pop((model_id, c.rid), None)
-                    if fut is not None and not fut.cancelled():
-                        fut.set_result(Served(
-                            model_id, c.rid, c.y, c.arrival, finish,
-                            finish - c.arrival, c.bucket, c.batched_rows))
+            with TraceAnnotation("serving.launch") as span:
+                try:
+                    done, bucket, _dt = batcher.run_one()
+                except Exception as exc:           # noqa: BLE001
+                    self._degrade(model_id, batcher, exc)
+                    continue
+                finally:
+                    with self._cond:
+                        ss["inflight"] = False
+                finish = self.clock()
+                with TraceAnnotation("serving.scatter"), self._cond:
+                    self._fail_streak.pop(model_id, None)
+                    self.stats["launches"] += 1
+                    self._model_stats(model_id)["launches"] += 1
+                    for c in done:
+                        fut = self._futures.pop((model_id, c.rid), None)
+                        if fut is not None and not fut.cancelled():
+                            fut.set_result(Served(
+                                model_id, c.rid, c.y, c.arrival, finish,
+                                finish - c.arrival, c.bucket,
+                                c.batched_rows))
+                span.set_metadata(
+                    bucket=bucket, requests=len(done),
+                    rows=done[0].batched_rows if done else 0)
 
     # ------------------------------------------- multi-stream dispatch
 
@@ -1072,43 +1096,48 @@ class ServingFrontend:
                 ss = self.stats["streams"][idx]
                 ss["last_launch_s"] = self.clock()   # watchdog heartbeat
                 ss["inflight"] = True
-            try:
-                done, _bucket, _dt = batcher.execute(
-                    taken, device=self._devices[idx])
-            except Exception as exc:          # noqa: BLE001
-                with self._cond:
+            with TraceAnnotation("serving.launch") as span:
+                try:
+                    done, bucket, _dt = batcher.execute(
+                        taken, device=self._devices[idx],
+                        record_devices=True)
+                except Exception as exc:          # noqa: BLE001
+                    with self._cond:
+                        ss["inflight"] = False
+                        self._stream_load[idx] = max(
+                            0.0, self._stream_load[idx] - est)
+                        self._stream_inflight -= 1
+                        self._cond.notify_all()
+                    self._degrade_stream(idx, model_id, batcher, exc)
+                    continue
+                finish = self.clock()
+                dt = time.perf_counter() - t0
+                with TraceAnnotation("serving.scatter"), self._cond:
                     ss["inflight"] = False
                     self._stream_load[idx] = max(
                         0.0, self._stream_load[idx] - est)
                     self._stream_inflight -= 1
+                    self._stream_streak[idx] = 0
+                    self._fail_streak.pop(model_id, None)
+                    self.stats["launches"] += 1
+                    self._model_stats(model_id)["launches"] += 1
+                    ss = self.stats["streams"][idx]
+                    ss["launches"] += 1
+                    ss["busy_s"] += dt
+                    for key, names in zip(
+                            ("batch_devices", "result_devices"),
+                            taken.devices):
+                        ss[key] = sorted(set(ss[key]) | set(names))
+                    for c in done:
+                        fut = self._futures.pop((model_id, c.rid), None)
+                        if fut is not None and not fut.cancelled():
+                            fut.set_result(Served(
+                                model_id, c.rid, c.y, c.arrival, finish,
+                                finish - c.arrival, c.bucket,
+                                c.batched_rows, stream=idx))
                     self._cond.notify_all()
-                self._degrade_stream(idx, model_id, batcher, exc)
-                continue
-            finish = self.clock()
-            dt = time.perf_counter() - t0
-            with self._cond:
-                ss["inflight"] = False
-                self._stream_load[idx] = max(
-                    0.0, self._stream_load[idx] - est)
-                self._stream_inflight -= 1
-                self._stream_streak[idx] = 0
-                self._fail_streak.pop(model_id, None)
-                self.stats["launches"] += 1
-                self._model_stats(model_id)["launches"] += 1
-                ss = self.stats["streams"][idx]
-                ss["launches"] += 1
-                ss["busy_s"] += dt
-                for key, names in zip(("batch_devices", "result_devices"),
-                                      taken.devices or ((), ())):
-                    ss[key] = sorted(set(ss[key]) | set(names))
-                for c in done:
-                    fut = self._futures.pop((model_id, c.rid), None)
-                    if fut is not None and not fut.cancelled():
-                        fut.set_result(Served(
-                            model_id, c.rid, c.y, c.arrival, finish,
-                            finish - c.arrival, c.bucket, c.batched_rows,
-                            stream=idx))
-                self._cond.notify_all()
+                span.set_metadata(bucket=bucket, requests=len(done),
+                                  rows=taken.rows)
 
     def _loop_multi(self) -> None:
         with self._cond:
@@ -1145,9 +1174,10 @@ class ServingFrontend:
                         pick = self._pick(now)
                         if pick is None:
                             deadline = self.registry.next_deadline()
-                            self._cond.wait(
-                                None if deadline is None
-                                else max(deadline - now, 0.0))
+                            with TraceAnnotation("serving.wait"):
+                                self._cond.wait(
+                                    None if deadline is None
+                                    else max(deadline - now, 0.0))
                             continue
                 model_id, batcher = pick
                 taken = batcher.take()

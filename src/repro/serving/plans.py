@@ -54,6 +54,7 @@ from typing import (Callable, Dict, List, Optional, Protocol, Sequence,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as kops
 from ..kernels.fantastic4_fused_mlp import (VMEM_BUDGET_BYTES,
@@ -638,16 +639,18 @@ class ExecutionPlan:
     def run(self, x: jax.Array) -> jax.Array:
         """Serve one batch: pad rows up to the resolved bucket, execute its
         entry, slice the real rows back out.  Batches past the largest
-        bucket run at exact size (the megakernel grids over row tiles)."""
-        x = x.astype(jnp.float32)
-        m = x.shape[0]
-        b = self.bucket_for(m)
-        if b is None:
-            obp = self.oversize_binding(m)
-            return self._execute(x, obp.path, block_m=obp.block_m)
-        if m < b:
-            x = jnp.pad(x, ((0, b - m), (0, 0)))
-        return self.entry(b)(x)[:m]
+        bucket run at exact size (the megakernel grids over row tiles).
+        One call is the profiler span ``serving.plan_run``."""
+        with TraceAnnotation("serving.plan_run"):
+            x = x.astype(jnp.float32)
+            m = x.shape[0]
+            b = self.bucket_for(m)
+            if b is None:
+                obp = self.oversize_binding(m)
+                return self._execute(x, obp.path, block_m=obp.block_m)
+            if m < b:
+                x = jnp.pad(x, ((0, b - m), (0, 0)))
+            return self.entry(b)(x)[:m]
 
     def __call__(self, x: jax.Array) -> jax.Array:
         return self.run(x)

@@ -1,6 +1,9 @@
 """MicroBatcher: padding/coalescing parity (a request served from a padded
 bucket must equal serving it alone — bit-identical on the int8 paths),
 deadline-based partial flush, FIFO scatter, and the replay simulator."""
+import time
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -100,6 +103,47 @@ def test_deadline_partial_flush():
     done = b.pump(now=10.6)                # deadline hit: partial flush
     assert [c.rid for c in done] == [rid]
     assert done[0].bucket == 1
+
+
+def test_queue_wait_counts_a_known_wait():
+    """``queue_wait_s`` sums launch start minus arrival over the launched
+    requests, on the live clock."""
+    plan = _plan(_rand_pack(EVEN_DIMS))
+    b = serving.MicroBatcher(plan, max_delay=1.0)
+    x = np.zeros((1, EVEN_DIMS[0]), np.float32)
+    b.submit(x)
+    b.submit(x)
+    time.sleep(0.005)
+    b.flush()
+    assert b.stats["flushed_requests"] == 2
+    assert b.stats["flushes"] == 1
+    assert 2 * 0.005 <= b.stats["queue_wait_s"] < 2 * 60.0
+
+
+def test_queue_wait_stays_off_a_virtual_clock():
+    """A virtual clock's seconds are not the live ones: the wait is the
+    driver's to account, as ``compute_s`` is; the requests still count."""
+    plan = _plan(_rand_pack(EVEN_DIMS))
+    b = serving.MicroBatcher(plan, max_delay=0.5, clock=None)
+    b.submit(np.zeros((1, EVEN_DIMS[0]), np.float32), now=0.0)
+    b.flush(now=3.0)
+    assert b.stats["flushed_requests"] == 1
+    assert b.stats["queue_wait_s"] == 0.0
+
+
+def test_execute_records_devices_only_when_asked():
+    plan = _plan(_rand_pack(EVEN_DIMS))
+    b = serving.MicroBatcher(plan)
+    x = np.zeros((1, EVEN_DIMS[0]), np.float32)
+    b.submit(x)
+    t = b.take()
+    b.execute(t)
+    assert t.devices is None
+    b.submit(x)
+    t = b.take()
+    b.execute(t, record_devices=True)
+    here = (str(jax.devices()[0]),)
+    assert t.devices == (here, here)
 
 
 def test_multi_row_requests_stay_contiguous():

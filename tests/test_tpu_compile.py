@@ -12,6 +12,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +49,12 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compiled_kernel_text(fn, *args) -> str:
+def _compiled_kernel_text(name, fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text          # a Mosaic kernel, compiled
+    # under its stable name, whatever jit wraps it: the device trace names
+    # each op event by its HLO instruction
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(", text), name
     return text
 
 
@@ -73,6 +77,7 @@ def test_megakernel_schedule_compiles(one_chip, schedule, act_dtype):
                   else F.fantastic4_fused_mlp_stream_pallas)
         kw = {} if schedule == "ws" else {"block_m": 32}
         _compiled_kernel_text(
+            f"fantastic4_fused_mlp_{schedule}_pallas",
             lambda x, *ops: kernel(x, *ops, shapes=SHAPES, activations=ACTS,
                                    act_dtype=act_dtype, **kw),
             x, *stacked)
@@ -84,6 +89,7 @@ def test_megakernel_schedule_compiles(one_chip, schedule, act_dtype):
         tuple(s((n,), jnp.float32) for _, n in SHAPES),
         tuple(s((), jnp.float32) for _ in SHAPES))
     _compiled_kernel_text(
+        "fantastic4_fused_mlp_pallas",
         lambda x, *ops: F.fantastic4_fused_mlp_pallas(
             x, *ops, shapes=SHAPES, activations=ACTS, block_m=ROWS,
             act_dtype=act_dtype, double_buffer=schedule == "db"),
@@ -95,6 +101,7 @@ def test_per_layer_kernel_compiles(one_chip, k, n):
     s = lambda shape, dtype: _spec(one_chip, shape, dtype)  # noqa: E731
     cfg = autotune.heuristic_blocks(ROWS, k, n, backend="tpu")
     _compiled_kernel_text(
+        "fantastic4_matmul_pallas",
         lambda *a: MM.fantastic4_matmul_pallas(
             *a, activation="relu", block_m=cfg.block_m,
             block_n=cfg.block_n, block_k=cfg.block_k),
