@@ -23,6 +23,25 @@ before the single HBM store.  ``fused_mlp_vmem_bytes`` budgets that
 activation working set either way.  The grid is 1-D over batch tiles
 (weights use constant index maps, so they are fetched once and revisited).
 
+Where the decode happens depends on the grid alone
+(``fused_mlp_decode_once``):
+
+* **one batch tile** — each layer's codes are decoded in the grid step, as
+  a kernel value that feeds the layer's matmul (one step, one decode).
+* **several batch tiles**, when every layer's decoded W fits the VMEM
+  budget at once — the first grid step decodes every layer into its own
+  ``(K_l, N_l)`` f32 VMEM scratch (each at its own padded shape), and every
+  batch tile multiplies against those scratches: L decodes per call
+  instead of L per tile.  The grid then runs in order (``"arbitrary"``
+  semantics, since step i>0 reads what step 0 wrote); a one-core v5e loses
+  nothing by that, but a two-TensorCore (megacore) part would lose its
+  split of the batch tiles across cores on this path.
+* several tiles whose decoded stack would bust the budget — the per-step
+  decode of the one-tile case, tile after tile (``"parallel"``).
+
+Either way W holds the same values and meets the same ``lane_dot``, so the
+outputs are bit for bit the same.
+
 Two orthogonal variants on top of the PR-1 fp32 path:
 
 * ``act_dtype="int8"`` — the paper's §VI-C FPGA configuration (8-bit
@@ -77,17 +96,16 @@ budget, still in one launch.
 
 The fourth schedule — the **decode-amortized streaming** variant
 (``fantastic4_fused_mlp_stream_pallas``) — covers the mid-size batches
-where neither of the above dominates.  The batch-tiled kernel re-runs
-every layer's bit-plane decode (Σωᵢ·Bᵢ) once *per batch tile* (the weight
-operands are revisited but the decoded tile is a kernel value, rebuilt
-each grid step); the ws kernel decodes each layer once but cannot tile the
-batch at all (the whole batch rides in its scratch and meets one layer per
-step).  The streaming grid is ``(layers, batch tiles)`` ordered
-layers-outer / batch-tiles-inner: at step (l, 0) layer l's codes are
-decoded once into a persistent ``(D, D)`` VMEM scratch, and every
-subsequent batch tile of that layer reuses the decoded tile — decode runs
-**once per layer per inference batch**, L·T matmuls share L decodes.  The
-activation ping-pongs through a whole-batch ``(M, D)`` VMEM scratch
+where neither of the above dominates.  The batch-tiled kernel keeps the
+whole stack resident (decoded once per call where the decoded stack fits
+VMEM, else once per batch tile); the ws kernel decodes each layer once but
+cannot tile the batch at all (the whole batch rides in its scratch and
+meets one layer per step).  The streaming grid is ``(layers, batch
+tiles)`` ordered layers-outer / batch-tiles-inner: at step (l, 0) layer
+l's codes are decoded once into a persistent ``(D, D)`` VMEM scratch, and
+every subsequent batch tile of that layer reuses the decoded tile — decode
+runs **once per layer per inference batch**, L·T matmuls share L decodes.
+The activation ping-pongs through a whole-batch ``(M, D)`` VMEM scratch
 (tile i's rows are read and rewritten in place — row ranges are disjoint
 across tiles, so no tile ever reads another's output).  Per-step streamed
 VMEM is one layer's codes + the decoded tile + one batch tile, so like the
@@ -101,6 +119,7 @@ and the int8 grid is bit-identical across all four schedules.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -116,6 +135,9 @@ from .fantastic4_matmul import decode_tile, lane_dot, trim_padding
 DIM_ALIGN = 128
 # conservative per-core budget: 16 MiB VMEM minus pipelining headroom.
 VMEM_BUDGET_BYTES = 12 << 20
+# packed code rows per step of the decode into VMEM scratch: one uint8
+# (32, 128) tile of rows.
+DECODE_CHUNK = 32
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -133,7 +155,8 @@ def fused_mlp_vmem_bytes(shapes: Sequence[Tuple[int, int]],
                          dim_align: int = DIM_ALIGN,
                          act_dtype: str = "float32",
                          double_buffer: bool = False) -> int:
-    """Working-set estimate for one grid step (bytes).
+    """Working-set estimate for one grid step (bytes) of the per-step
+    decode: the kernel's form for a single batch tile.
 
     packed codes for all layers + the largest decoded W tile + the x tile,
     activation scratch, output tile and epilogue vectors; ×2 on the
@@ -141,7 +164,10 @@ def fused_mlp_vmem_bytes(shapes: Sequence[Tuple[int, int]],
     adds the quantized copy of the activation tile (1 byte/elem) that each
     epilogue materialises before the next layer's MXU op; the
     double-buffered schedule keeps up to two decoded W tiles live (layer l
-    serves row group 1 one tick after group 0).
+    serves row group 1 one tick after group 0).  Plan resolution and the
+    chain fallback decide on this estimate.  A multi-tile call that decodes
+    once holds every layer's decoded W instead of the largest
+    (``fused_mlp_decode_once``).
     """
     ps = padded_shapes(shapes, dim_align)
     packed = sum(kp // 2 * np_ for kp, np_ in ps)          # uint8
@@ -171,31 +197,88 @@ def fused_mlp_fits(shapes: Sequence[Tuple[int, int]], *,
                                 act_dtype, double_buffer) <= budget_bytes
 
 
+def fused_mlp_decode_once(shapes: Sequence[Tuple[int, int]], rows: int,
+                          block_m: int = 128,
+                          act_dtype: str = "float32",
+                          double_buffer: bool = False,
+                          budget_bytes: int = VMEM_BUDGET_BYTES,
+                          dim_align: int = DIM_ALIGN) -> bool:
+    """True when a batch-tiled call over ``rows`` decodes each layer once.
+
+    That is when the grid has more than one batch tile and the working set
+    with every layer's decoded W held in VMEM (the sum of 4·K_l·N_l over
+    layers in place of the largest tile; twice over on the double-buffered
+    schedule, where the TPU compiler takes about one more copy of it) fits
+    ``budget_bytes``.  Otherwise the kernel decodes in every grid step.
+    ``fantastic4_fused_mlp_pallas`` decides with this at the default
+    budget; serving plans report it.
+    """
+    if not shapes:
+        return False
+    bm = min(block_m, _round_up(rows, 8))
+    if _round_up(rows, bm) // bm < 2:
+        return False
+    copies = 2 if double_buffer else 1
+    sizes = [4 * kp * np_ for kp, np_ in padded_shapes(shapes, dim_align)]
+    held = (fused_mlp_vmem_bytes(shapes, bm, dim_align, act_dtype,
+                                 double_buffer)
+            + copies * (sum(sizes) - max(sizes)))
+    return held <= budget_bytes
+
+
+def _decode_into(w_ref, packed_ref, omega_ref) -> None:
+    """``w_ref[...] = decode_tile(packed_ref[...], omega_ref)``, one
+    ``DECODE_CHUNK`` of code rows per loop step, so that the decode's int32
+    and bit-plane temporaries take one chunk of VMEM, not one layer."""
+    rows = packed_ref.shape[0]
+    chunk = math.gcd(rows, DECODE_CHUNK)
+
+    def step(i, carry):
+        r = pl.multiple_of(i * chunk, chunk)
+        w_ref[pl.ds(2 * r, 2 * chunk), :] = decode_tile(
+            packed_ref[pl.ds(r, chunk), :], omega_ref)
+        return carry
+
+    jax.lax.fori_loop(0, rows // chunk, step, 0)
+
+
 def _kernel(*refs, activations: Tuple[Optional[str], ...],
-            act_dtype: str, n_halves: int):
+            act_dtype: str, n_halves: int, decode_once: bool):
     n_layers = len(activations)
     x_ref = refs[0]
     layer_refs = refs[1:1 + 5 * n_layers]
     o_ref = refs[1 + 5 * n_layers]
     act_ref = refs[2 + 5 * n_layers]          # (bm, max_width) VMEM scratch
+    w_refs = refs[3 + 5 * n_layers:]          # per-layer decoded W scratch
     int8_acts = act_dtype == "int8"
 
-    # Each layer's weight tile is decoded once and shared across row
-    # groups: in the skewed schedule layer l serves group 0 at tick l and
-    # group 1 at tick l+1, so the decoded tile stays live for exactly one
-    # extra tick (≤2 decoded tiles concurrently) instead of being decoded
-    # per group.  The python-level dict is static — the compiler sees one
-    # decode_tile per layer either way.
+    if decode_once:
+        # the first batch tile decodes every layer into its scratch, which
+        # persists across grid steps: every later tile reads it.
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            for l, w_ref in enumerate(w_refs):
+                _decode_into(w_ref, *layer_refs[5 * l:5 * l + 2])
+
+    # Per-step decode: each layer's weight tile is decoded once and shared
+    # across row groups: in the skewed schedule layer l serves group 0 at
+    # tick l and group 1 at tick l+1, so the decoded tile stays live for
+    # exactly one extra tick (≤2 decoded tiles concurrently) instead of
+    # being decoded per group.  The python-level dict is static — the
+    # compiler sees one decode_tile per layer either way.
     decoded = {}
 
     def apply_layer(cur: jax.Array, l: int, last_use: bool) -> jax.Array:
         packed_ref, omega_ref, alpha1_ref, bias_ref, scale_ref = \
             layer_refs[5 * l:5 * l + 5]
-        if l not in decoded:
-            decoded[l] = decode_tile(packed_ref[...], omega_ref)
-        w = decoded[l]
-        if last_use:
-            del decoded[l]
+        if decode_once:
+            w = w_refs[l]     # lane_dot loads one 128-column slab at a time
+        else:
+            if l not in decoded:
+                decoded[l] = decode_tile(packed_ref[...], omega_ref)
+            w = decoded[l]
+            if last_use:
+                del decoded[l]
         y = lane_dot(cur, w)
         y = y * alpha1_ref[...] + bias_ref[...]
         y = ref.apply_activation(y, activations[l])
@@ -268,6 +351,8 @@ def fantastic4_fused_mlp_pallas(
     does.  ``double_buffer`` splits the batch tile into two row groups on
     the skewed schedule described in the module docstring (it needs two
     full sublane groups, so it engages only when the tile has ≥16 rows).
+    A call of several batch tiles decodes each layer once into VMEM
+    scratch where the decoded stack fits (``fused_mlp_decode_once``).
     """
     assert act_dtype in ("float32", "int8"), act_dtype
     n_layers = len(shapes)
@@ -306,16 +391,24 @@ def fantastic4_fused_mlp_pallas(
 
     n_last_p = ps[-1][1]
     max_width = max([ps[0][0]] + [np_ for _, np_ in ps])
+    decode_once = fused_mlp_decode_once(shapes, m, block_m, act_dtype,
+                                        double_buffer, dim_align=dim_align)
+    w_scratch = [pltpu.VMEM(p, jnp.float32) for p in ps] if decode_once \
+        else []
     out = pl.pallas_call(
         functools.partial(_kernel, activations=tuple(activations),
-                          act_dtype=act_dtype, n_halves=n_halves),
+                          act_dtype=act_dtype, n_halves=n_halves,
+                          decode_once=decode_once),
         grid=(mp // bm,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, n_last_p), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, n_last_p), out_dtype),
-        scratch_shapes=[pltpu.VMEM((bm, max_width), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bm, max_width), jnp.float32)]
+        + w_scratch,
+        # tiles after the first read the scratch the first one decoded
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("arbitrary" if decode_once
+                                 else "parallel",)),
         interpret=interpret,
         name="fantastic4_fused_mlp_pallas",
     )(*operands)
@@ -548,7 +641,7 @@ def _stream_kernel(x_ref, packed_ref, omega_ref, alpha1_ref, bias_ref,
         # THE amortization: layer l's bit-plane decode runs once per
         # inference batch, at its first batch tile, into a scratch that
         # persists across grid steps — every later tile of this layer
-        # reuses it (the batch-tiled kernel redoes this per grid step).
+        # reuses it.
         w_ref[...] = decode_tile(packed_ref[0], omega_ref[0])
 
     cur = act_ref[pl.ds(i * block_m, block_m), :]

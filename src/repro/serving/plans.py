@@ -58,6 +58,7 @@ from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as kops
 from ..kernels.fantastic4_fused_mlp import (VMEM_BUDGET_BYTES,
+                                            fused_mlp_decode_once,
                                             fused_mlp_fits,
                                             stream_mlp_fits, ws_mlp_fits)
 from ..kernels import autotune
@@ -429,6 +430,7 @@ class ExecutionPlan:
 
         self._entries: Dict[int, Callable] = {}
         self._oversize_memo: Dict[int, BucketPlan] = {}
+        self._decode_memo: Dict[BucketPlan, str] = {}
 
     # ------------------------------------------------------------ resolve
 
@@ -640,14 +642,15 @@ class ExecutionPlan:
         """Serve one batch: pad rows up to the resolved bucket, execute its
         entry, slice the real rows back out.  Batches past the largest
         bucket run at exact size (the megakernel grids over row tiles).
-        One call is the profiler span ``serving.plan_run``."""
-        with TraceAnnotation("serving.plan_run"):
+        One call is the profiler span ``serving.plan_run``, with metadata
+        ``decode`` (``decode_form`` of the binding that runs)."""
+        m = x.shape[0]
+        b = self.bucket_for(m)
+        bp = self.oversize_binding(m) if b is None else self.buckets[b]
+        with TraceAnnotation("serving.plan_run", decode=self.decode_form(bp)):
             x = x.astype(jnp.float32)
-            m = x.shape[0]
-            b = self.bucket_for(m)
             if b is None:
-                obp = self.oversize_binding(m)
-                return self._execute(x, obp.path, block_m=obp.block_m)
+                return self._execute(x, bp.path, block_m=bp.block_m)
             if m < b:
                 x = jnp.pad(x, ((0, b - m), (0, 0)))
             return self.entry(b)(x)[:m]
@@ -677,6 +680,25 @@ class ExecutionPlan:
         path = self.path_for(m)
         return SCHEDULE_BY_PATH.get(path, path)
 
+    def decode_form(self, bp: BucketPlan) -> str:
+        """How one call of a binding decodes the 4-bit codes: ``"once"``
+        per layer, or ``"per_tile"``, again in each row tile.  The
+        batch-tiled kernel decodes once when it runs several tiles and the
+        decoded stack fits VMEM (``fused_mlp_decode_once``), else per tile
+        (a single tile included); ws, stream and the jnp oracle decode
+        once; the per-layer kernel, which the sharded program runs too,
+        per tile."""
+        form = self._decode_memo.get(bp)
+        if form is None:
+            if bp.path in ("fused", "fused_db"):
+                once = fused_mlp_decode_once(
+                    self.shapes, bp.rows, bp.block_m or self.block_m,
+                    self.act_dtype, double_buffer=bp.path == "fused_db")
+            else:
+                once = bp.path not in ("per_layer", "sharded")
+            form = self._decode_memo[bp] = "once" if once else "per_tile"
+        return form
+
     def describe(self) -> dict:
         return {
             "requested_mode": self.requested_mode,
@@ -693,6 +715,13 @@ class ExecutionPlan:
                                for b, p in self.buckets.items()},
             "bucket_sources": {b: p.source
                                for b, p in self.buckets.items()},
+            "bucket_decode": {b: self.decode_form(p)
+                              for b, p in self.buckets.items()},
+            # the batches past the largest bucket served so far
+            "oversize_bindings": {
+                m: {"path": p.path, "block_m": p.block_m,
+                    "decode": self.decode_form(p)}
+                for m, p in self._oversize_memo.items()},
             "ws_crossover_rows": self.ws_crossover_rows,
             "ws_prior_rows": self.ws_prior_rows,
             "ws_prior_source": self.ws_prior_source,

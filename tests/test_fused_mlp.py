@@ -11,7 +11,8 @@ import pytest
 from repro.configs.paper_mlps import MLPS
 from repro.core import bitplanes as bp
 from repro.kernels import ops, ref
-from repro.kernels.fantastic4_fused_mlp import (fused_mlp_fits,
+from repro.kernels.fantastic4_fused_mlp import (fused_mlp_decode_once,
+                                                fused_mlp_fits,
                                                 fused_mlp_vmem_bytes,
                                                 stream_mlp_fits,
                                                 stream_mlp_vmem_bytes)
@@ -169,6 +170,69 @@ def test_stream_schedule_decode_amortized_paths_match():
                                      schedule="stream", block_m=bm)
         np.testing.assert_allclose(y, _oracle(pack, x), atol=1e-3,
                                    rtol=1e-4, err_msg=str((dims, batch, bm)))
+
+
+GSC_DIMS = STACKS["mlp-gsc"]
+
+
+@pytest.mark.parametrize("dims,rows,bm,act_dtype,schedule", [
+    (STACKS["odd"], 37, 16, "float32", "batch_tiled"),    # ragged last tile
+    (STACKS["lenet-300-100"], 40, 8, "float32", "batch_tiled"),
+    (GSC_DIMS, 40, 16, "int8", "batch_tiled"),             # 3 tiles
+    (STACKS["odd"], 40, 16, "float32", "db"),              # 16-row tiles
+], ids=["ragged", "lenet", "gsc-int8", "db"])
+def test_multi_tile_decode_once_is_bitwise_the_per_tile_decode(
+        dims, rows, bm, act_dtype, schedule):
+    """A call of several batch tiles decodes each layer once into VMEM
+    scratch; each tile's rows run as a call of one tile (zero rows fill a
+    ragged last tile, as the kernel pads it) take the per-step decode.  W
+    holds the same values and meets the same dot, so the logits agree bit
+    for bit.  (Against one tile of all rows they need not: XLA's CPU dot
+    rounds a row differently at another row count.)"""
+    shapes = tuple(zip(dims[:-1], dims[1:]))
+    assert fused_mlp_decode_once(shapes, rows, bm, act_dtype,
+                                 double_buffer=schedule == "db")
+    assert not fused_mlp_decode_once(shapes, bm, bm, act_dtype,
+                                     double_buffer=schedule == "db")
+    pack = _rand_pack(dims, seed=rows + bm)
+    x = jnp.asarray(np.random.default_rng(rows).normal(size=(rows, dims[0])),
+                    jnp.float32)
+    kw = {"interpret": True, "block_m": bm, "schedule": schedule}
+    if act_dtype == "int8":
+        kw.update(act_dtype="int8",
+                  act_scales=[0.05] * (len(pack["layers"]) - 1))
+    y = np.asarray(ops.fantastic4_mlp_fused(x, pack["layers"], **kw))
+    tiles = []
+    for i in range(0, rows, bm):
+        t = x[i:i + bm]
+        one = jnp.pad(t, ((0, bm - t.shape[0]), (0, 0)))
+        tiles.append(np.asarray(
+            ops.fantastic4_mlp_fused(one, pack["layers"], **kw))[:len(t)])
+    np.testing.assert_array_equal(y, np.concatenate(tiles))
+    # and the result is the stack's, not only self-consistent
+    if act_dtype == "float32":
+        np.testing.assert_allclose(y, _oracle(pack, x), atol=1e-3,
+                                   rtol=1e-5)
+
+
+def test_decode_once_needs_several_tiles_and_the_decoded_stack_in_vmem():
+    for name, rows, bm, act in (("lenet-300-100", 65536, 32, "float32"),
+                                ("mlp-gsc", 65536, 128, "int8"),
+                                ("mlp-hr", 300, 128, "float32")):
+        dims = STACKS[name]
+        shapes = tuple(zip(dims[:-1], dims[1:]))
+        assert fused_mlp_decode_once(shapes, rows, bm, act), name
+        assert fused_mlp_decode_once(shapes, rows, bm, act,
+                                     double_buffer=True), name
+        # one tile, however the rows fall: the per-step decode
+        for one in (1, bm - 3, bm):
+            assert not fused_mlp_decode_once(shapes, one, bm, act), name
+    # four 1024x1024 layers: 4 MiB decoded each.  The per-step working
+    # set fits the budget, all four held at once do not.
+    wide = ((1024, 1024),) * 4
+    assert fused_mlp_fits(wide, block_m=128)
+    assert not fused_mlp_decode_once(wide, 1024, 128)
+    assert not fused_mlp_decode_once((), 1024, 128)
 
 
 def test_gelu_activation_matches_on_every_schedule():

@@ -85,6 +85,39 @@ def test_run_pads_to_bucket_and_slices_back():
         np.testing.assert_allclose(y, oracle.run(x), atol=1e-3, rtol=1e-4)
 
 
+def test_plan_run_span_and_describe_name_the_decode_form(tmp_path):
+    """``serving.plan_run`` carries metadata ``decode``, and describe()
+    the same field: ``once`` for a LeNet batch past the largest bucket
+    (five 8-row tiles), ``per_tile`` for a bucket of one tile."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    plan = serving.build_plan(_rand_pack((784, 300, 100, 10)), mode="fused",
+                              interpret=True, max_bucket=8, ws_bucket_rows=0)
+    assert plan.describe()["bucket_paths"][8] == "fused"
+    rng = np.random.default_rng(0)
+    big = jnp.asarray(rng.normal(size=(40, 784)), jnp.float32)
+    small = jnp.asarray(rng.normal(size=(8, 784)), jnp.float32)
+    for x in (big, small):                    # compile outside the trace
+        plan.run(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for x in (big, small):
+            plan.run(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    runs = sorted((e.start_ns, dict(e.stats)["decode"])
+                  for p in data.planes for line in p.lines
+                  for e in line.events if e.name == "serving.plan_run")
+    assert [form for _, form in runs] == ["once", "per_tile"]
+    d = plan.describe()
+    assert d["oversize_bindings"] == {
+        40: {"path": "fused", "block_m": 8, "decode": "once"}}
+    assert d["bucket_decode"][8] == "per_tile"
+
+
 def test_entry_is_cached_and_shape_checked():
     plan = serving.build_plan(_rand_pack(DIMS), mode="fused", interpret=True)
     assert plan.entry(4) is plan.entry(4)

@@ -5,8 +5,10 @@ checks what only the TPU compiler refuses: casts Mosaic has no lowering
 for, blocks off the (8, 128) tiling, more VMEM than a kernel may use.  Each
 test here lowers one kernel for one chip of a described ``v5e:2x2`` and
 compiles it: the four megakernel schedules at MLP-GSC's widths in fp32 and
-int8, and the per-layer kernel at its heuristic TPU blocks for the last
-MLP-GSC layer and an LM FFN's two matmuls (smollm-360m, 960 <-> 2560).
+int8, the batch-tiled and db ones also over several batch tiles (decoded
+once into VMEM scratch), and the per-layer kernel at its heuristic TPU
+blocks for the last MLP-GSC layer and an LM FFN's two matmuls
+(smollm-360m, 960 <-> 2560).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -62,6 +64,15 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _per_layer_specs(s):
+    """The batch-tiled kernel's per-layer operands at MLP-GSC's widths."""
+    return (tuple(s((k // 2, n), jnp.uint8) for k, n in SHAPES),
+            tuple(s((4,), jnp.float32) for _ in SHAPES),
+            tuple(s((n,), jnp.float32) for _, n in SHAPES),
+            tuple(s((n,), jnp.float32) for _, n in SHAPES),
+            tuple(s((), jnp.float32) for _ in SHAPES))
+
+
 @pytest.mark.parametrize("act_dtype", ["float32", "int8"])
 @pytest.mark.parametrize("schedule", ["batch_tiled", "db", "ws", "stream"])
 def test_megakernel_schedule_compiles(one_chip, schedule, act_dtype):
@@ -82,18 +93,31 @@ def test_megakernel_schedule_compiles(one_chip, schedule, act_dtype):
                                    act_dtype=act_dtype, **kw),
             x, *stacked)
         return
-    per_layer = (
-        tuple(s((k // 2, n), jnp.uint8) for k, n in SHAPES),
-        tuple(s((4,), jnp.float32) for _ in SHAPES),
-        tuple(s((n,), jnp.float32) for _, n in SHAPES),
-        tuple(s((n,), jnp.float32) for _, n in SHAPES),
-        tuple(s((), jnp.float32) for _ in SHAPES))
+    per_layer = _per_layer_specs(s)
     _compiled_kernel_text(
         "fantastic4_fused_mlp_pallas",
         lambda x, *ops: F.fantastic4_fused_mlp_pallas(
             x, *ops, shapes=SHAPES, activations=ACTS, block_m=ROWS,
             act_dtype=act_dtype, double_buffer=schedule == "db"),
         x, *per_layer)
+
+
+@pytest.mark.parametrize("act_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("schedule", ["batch_tiled", "db"])
+def test_megakernel_decode_once_compiles(one_chip, schedule, act_dtype):
+    """Several batch tiles: the first grid step decodes every layer into
+    VMEM scratch and the grid runs in order."""
+    block_m = ROWS // 4
+    assert F.fused_mlp_decode_once(SHAPES, ROWS, block_m, act_dtype,
+                                   double_buffer=schedule == "db")
+    s = lambda shape, dtype: _spec(one_chip, shape, dtype)  # noqa: E731
+    per_layer = _per_layer_specs(s)
+    _compiled_kernel_text(
+        "fantastic4_fused_mlp_pallas",
+        lambda x, *ops: F.fantastic4_fused_mlp_pallas(
+            x, *ops, shapes=SHAPES, activations=ACTS, block_m=block_m,
+            act_dtype=act_dtype, double_buffer=schedule == "db"),
+        s((ROWS, GSC[0]), jnp.float32), *per_layer)
 
 
 @pytest.mark.parametrize("k,n", [(128, 12), (960, 2560), (2560, 960)])
