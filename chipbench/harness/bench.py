@@ -5,13 +5,16 @@
 
 A run loads ``workloads/<cell>.json`` and its ``configs/<config>.json``,
 makes the model from the seed (``models/<family>.py``), builds the plan
-with the committed block bindings (no sweep), lets ``traffic/<kind>.py``
+with the configuration's committed block bindings
+(``bindings/<config>.json``; no sweep), lets ``traffic/<kind>.py``
 warm up exactly the shapes its traffic uses, measures for ``--seconds``,
 checks what the timed path produced against the plain reference, and
 prints one JSON line.  With ``--trace 1`` the window runs under the
 profiler and the line carries the per-layer metrics instead of the
-end-to-end ones.  ``--sweep`` runs the window once per offered rate
-(open-loop traffic only), prints one line per rate and no result.
+end-to-end ones; the readers find the trace's reduction, the program's
+``serving.*`` spans among it (``harness/spans.py``).  ``--sweep`` runs
+the window once per offered rate (open-loop traffic only), prints one
+line per rate and no result.
 """
 from __future__ import annotations
 
@@ -24,15 +27,14 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import numpy as np
 
-from . import loader, trace as tracemod
-from .costs import layer_shapes
+from . import loader, spans, trace as tracemod
 from .meter import CompileMeter
 
-BINDINGS = os.path.join(loader.BENCH, "bindings", "autotune.json")
+RECORD = "python3 chipbench/tools/record_bindings.py --config"
 
 
 def parse(argv: Optional[List[str]]) -> argparse.Namespace:
@@ -48,13 +50,18 @@ def parse(argv: Optional[List[str]]) -> argparse.Namespace:
 
 class Readings:
     """What the metric readers read: the window's numbers, the trace's
-    reduction, the set-up split and the cell's shapes and device."""
+    reduction (``trace``; its ``spans`` the program's own, as
+    ``harness.spans.reduce`` gives them) and the trace file it came from,
+    the set-up split, and the cell's model family, shapes and device."""
 
-    def __init__(self, run: "Run", window: dict, reduced: Optional[dict]):
+    def __init__(self, run: "Run", window: dict, reduced: Optional[dict],
+                 xspace: Optional[str] = None):
         self.run = run
         self.window = window
         self.trace = reduced
+        self.xspace = xspace
         self.setup_s = run.setup_s
+        self.family = run.family
         self.shapes = run.shapes
         self.act_dtype = run.config["act_dtype"]
         self.device_kind = run.device_kind
@@ -71,7 +78,8 @@ class Run:
         self.params = workload.get("params", {})
         self.config = config
         self.chips = int(workload["chips"])
-        self.shapes = layer_shapes(config)
+        self.family = loader.family(config["family"])
+        self.shapes = self.family.shapes(config)
         self.t_start = t_start
         self.split = {}
         self.setup_s = None
@@ -103,9 +111,9 @@ def _memory_peak(devices) -> int:
     return max(peaks) if peaks else 0
 
 
-def use_bindings(tmp: str, path: str = BINDINGS) -> None:
-    """Point the block autotuner at a copy of the committed bindings in
-    ``tmp`` (the program writes into the file it is given) and turn on
+def use_bindings(tmp: str, path: str) -> None:
+    """Point the block autotuner at a copy in ``tmp`` of the bindings file
+    ``path`` (the program writes into the file it is given) and turn on
     JAX's persistent compile cache at the program's fixed place."""
     from repro.kernels import autotune
     from repro.launch import compile_cache
@@ -118,11 +126,16 @@ def use_bindings(tmp: str, path: str = BINDINGS) -> None:
 
 def execute(argv: Optional[List[str]] = None, *, require_chip: bool = True,
             workload: Optional[dict] = None, config: Optional[dict] = None,
-            bench: Optional[dict] = None,
-            t_start: Optional[float] = None) -> int:
-    """Run once; returns the exit code.  ``require_chip=False`` (tests
-    only) skips the look for a TPU and the committed bindings, and takes
-    the workload, config and metric list as given."""
+            bench: Optional[dict] = None, t_start: Optional[float] = None,
+            on_readings: Optional[Callable[[Readings], None]] = None
+            ) -> int:
+    """Run once; returns the exit code.  A configuration read from its
+    file runs on its committed bindings, and a run without them exits 1.
+    Tests only: ``require_chip=False`` skips the look for a TPU, and the
+    workload, config and metric list are taken as given (a config given
+    has no bindings file; its plan binds as the autotuner finds).
+    ``on_readings`` (tools) is called with the run's readings once the
+    metrics are read; the trace file they name lasts until it returns."""
     t_start = time.perf_counter() if t_start is None else t_start
     args = parse(argv)
     wl = workload or loader.workload(args.workload)
@@ -144,37 +157,43 @@ def execute(argv: Optional[List[str]] = None, *, require_chip: bool = True,
     used = devices[:run.chips]
     run.device_kind = used[0].device_kind
 
+    pinned = None if config is not None else \
+        loader.bindings_path(cfg["name"])
+    if pinned is not None and not os.path.isfile(pinned):
+        _log(f"chipbench: configuration {cfg['name']!r} has no block "
+             f"bindings ({os.path.relpath(pinned, loader.ROOT)}); record "
+             f"them on the chip with {RECORD} {cfg['name']}")
+        return 1
     tmp = tempfile.mkdtemp(prefix="chipbench-")
     try:
-        if require_chip:
-            use_bindings(tmp)
+        if pinned is not None:
+            use_bindings(tmp, pinned)
         meter = CompileMeter()
-        return _run(run, spec, used, meter, tmp, require_chip)
+        return _run(run, spec, used, meter, tmp, pinned, on_readings)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
 
 
-def _run(run: Run, spec: dict, used, meter, tmp: str,
-         require_chip: bool) -> int:
+def _run(run: Run, spec: dict, used, meter, tmp: str, pinned: Optional[str],
+         on_readings: Optional[Callable[[Readings], None]]) -> int:
     import jax
     from repro.kernels import autotune
     args = run.args
-    run.family = loader.family(run.config["family"])
     traffic = loader.traffic(run.workload["traffic"]).Traffic(run)
 
     t = time.perf_counter()
     run.split["start"] = t - run.t_start
     run.model = run.family.Model(run.config, run.seed)
-    jax.block_until_ready([l["packed"] for l in run.model.layers])
+    jax.block_until_ready(run.model.device_arrays())
     t = run.mark("weights", t)
     run.plan = run.model.plan()
     t = run.mark("plan", t)
-    if require_chip:
-        with open(BINDINGS) as a, open(autotune.cache_path()) as b:
+    if pinned is not None:
+        with open(pinned) as a, open(autotune.cache_path()) as b:
             if json.load(a) != json.load(b):
-                _log("chipbench: the plan build swept block bindings that "
-                     "chipbench/bindings/autotune.json lacks; record them "
-                     "with chipbench/tools/record_bindings.py")
+                _log(f"chipbench: the plan build swept block bindings "
+                     f"that {os.path.relpath(pinned, loader.ROOT)} lacks; "
+                     f"record them with {RECORD} {run.config['name']}")
                 return 1
     traffic.setup()
     t = run.mark("warmup", t)
@@ -190,9 +209,8 @@ def _run(run: Run, spec: dict, used, meter, tmp: str,
         f"persistent-cache hits {before['cache_hits']}, misses "
         f"{before['cache_misses']}")
     describe = run.plan.describe()
-    _log("plan: " + json.dumps({k: describe[k] for k in (
-        "resolved_mode", "block_m", "bucket_schedules", "bucket_block_m",
-        "default_path", "interpret")}))
+    _log("plan: " + json.dumps({k: describe[k]
+                                for k in run.family.PLAN_KEYS}))
 
     if args.sweep:
         for rate in [float(r) for r in args.sweep.split(",") if r]:
@@ -216,12 +234,15 @@ def _run(run: Run, spec: dict, used, meter, tmp: str,
     memory_peak = _memory_peak(used)
     traffic.close()
 
-    reduced = None
+    reduced = xspace = None
     if args.trace:
         t = time.perf_counter()
-        reduced = tracemod.reduce(tracemod.load(log_dir),
-                                  devices=[d.id for d in used])
-        shutil.rmtree(log_dir, ignore_errors=True)
+        xspace = tracemod.find_xspace(log_dir)
+        loaded = tracemod.load(xspace)
+        ids = [d.id for d in used]
+        reduced = tracemod.reduce(loaded, devices=ids)
+        reduced["spans"] = spans.reduce(loaded, devices=ids)
+        del loaded
         _log(f"trace: read in {time.perf_counter() - t:.3f} s; window "
              f"{reduced['window_s']:.6f} s, busy {reduced['busy_s']}, "
              f"kernel {reduced['kernel_s']}")
@@ -233,12 +254,14 @@ def _run(run: Run, spec: dict, used, meter, tmp: str,
     correct = window["failed_check"] == 0 and all(
         math.isfinite(v) and v <= limits[k] for k, v in checks.items())
 
-    readings = Readings(run, window, reduced)
+    readings = Readings(run, window, reduced, xspace)
     metrics = {}
     for entry in loader.metrics_for(spec, args.workload, bool(args.trace)):
         value = loader.metric_reader(entry["name"]).read(readings)
         if value is not None:
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    if on_readings is not None:
+        on_readings(readings)
     dev = used[0]
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": memory_peak}
@@ -272,7 +295,7 @@ def compare(run: Run, pairs, control: Optional[str] = None,
     rows that miss it (``models/<family>.miss_count``)."""
     names = list(run.workload["limits"] if names is None else names)
     worst, missed, rows = 0.0 if pairs else math.inf, 0.0, 0
-    d_out = run.shapes[-1][1]
+    d_out = run.model.d_out
     for x, y in pairs:
         if np.shape(y) != (np.shape(x)[0], d_out):
             worst, missed = math.inf, missed + np.shape(x)[0]

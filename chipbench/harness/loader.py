@@ -4,13 +4,17 @@ Everything that belongs to one configuration, one cell, one traffic kind or
 one per-layer metric lives in a file of its own, named after it:
 
     configs/<config>.json      the model: widths, dtypes, model family
+    bindings/<config>.json     its block bindings, recorded on the chip
     workloads/<cell>.json      the cell: config, traffic kind and its data
     traffic/<kind>.py          the generator for one kind of traffic
-    metrics/<metric>.py        the reader of one per-layer metric
-    models/<family>.py         weights, serving plan and plain reference
+    metrics/<metric>.py        the reader of one metric
+    models/<family>.py         weights, serving plan, plain reference, and
+                               the family's shapes and operation and byte
+                               counts
 
 and ``BENCHMARK.json`` at the root of the checkout says which metrics each
-cell reports.  Adding a cell or a metric adds files; nothing here changes.
+cell reports.  Adding a configuration, a model family, a cell or a metric
+adds files and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -27,9 +31,13 @@ ROOT = os.path.dirname(BENCH)
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
-def _path(kind: str, name: str, ext: str) -> str:
+def _check_name(kind: str, name: str) -> None:
     if not _NAME.match(name) or ".." in name:
         raise ValueError(f"bad {kind} name {name!r}")
+
+
+def _path(kind: str, name: str, ext: str) -> str:
+    _check_name(kind, name)
     path = os.path.join(BENCH, kind, name + ext)
     if not os.path.isfile(path):
         raise FileNotFoundError(f"no {kind} named {name!r} ({path})")
@@ -64,6 +72,13 @@ def config(name: str) -> dict:
     c = read_json("configs", name)
     c.setdefault("name", name)
     return c
+
+
+def bindings_path(config_name: str) -> str:
+    """Where configuration ``config_name``'s block bindings are kept; the
+    file need not exist yet."""
+    _check_name("configs", config_name)
+    return os.path.join(BENCH, "bindings", config_name + ".json")
 
 
 def traffic(kind: str) -> ModuleType:

@@ -7,7 +7,9 @@ layout a batch put on the device from host rows has), and cycled.  Each
 call's logits go to the host before the next call starts, so the window
 counts the device-to-host copy and the dispatch gap as a user of
 ``plan.run`` would see them.  The frontend and the micro-batcher are
-bypassed.
+bypassed.  A call longer than ``STALL_S`` (the host or the chip stalled)
+is counted, and the run's ``window:`` line gives the count, their seconds
+and the longest call.
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ from .trace import WINDOW_SPAN
 
 POOL = 2           # batches made at set-up and cycled
 CHECK_CALLS = 3    # calls whose logits are checked: the first and a sample
+STALL_S = 0.05     # a call this long (about ten times a call) is a stall
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
@@ -51,9 +54,9 @@ class Offline:
         # the first call's logits, and a reservoir sample of the others
         first, sample = None, []
         clock = time.perf_counter
-        n = 0
+        n, stalls, longest = 0, [], 0.0
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
-            t0 = clock()
+            t0 = last = clock()
             while True:
                 y = np.asarray(plan.run(batches[n % pool]))
                 if n == 0:
@@ -65,7 +68,12 @@ class Offline:
                     if j < len(sample):
                         sample[j] = (n, y)
                 n += 1
-                if clock() - t0 >= seconds:
+                now = clock()
+                took, last = now - last, now
+                longest = max(longest, took)
+                if took > STALL_S:
+                    stalls.append(took)
+                if now - t0 >= seconds:
                     break
             t1 = clock()
         window_s = t1 - t0
@@ -73,11 +81,14 @@ class Offline:
                 "window_s": window_s, "rows_done": n * self.rows,
                 "rows_per_s": n * self.rows / window_s,
                 "kernel_calls": n, "kernel_rows": n * self.rows,
-                "kept": dict([first] + sample)}
+                "longest_call_s": longest, "stalls": len(stalls),
+                "stall_s": sum(stalls), "kept": dict([first] + sample)}
 
     def summary(self, w: dict) -> dict:
         return {"calls": w["attempted"], "rows_per_call": self.rows,
                 "window_s": w["window_s"], "rows_per_s": w["rows_per_s"],
+                "longest_call_s": w["longest_call_s"],
+                "stalls": w["stalls"], "stall_s": w["stall_s"],
                 "checked_calls": sorted(w["kept"])}
 
     def close(self) -> None:

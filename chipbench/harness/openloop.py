@@ -115,7 +115,9 @@ class OpenLoop:
     def _counters(self) -> dict:
         st = self.batcher.stats
         return {"launches": st["flushes"], "rows": st["flushed_rows"],
-                "padded": st["padded_rows"]}
+                "padded": st["padded_rows"],
+                "requests": st["flushed_requests"],
+                "queue_wait_s": st["queue_wait_s"]}
 
     def window(self, seconds: float, rate: Optional[float] = None,
                rng_stream: int = 4) -> dict:
@@ -200,6 +202,8 @@ class OpenLoop:
             "launches": end["launches"] - start["launches"],
             "launch_rows": end["rows"] - start["rows"],
             "padded_rows": end["padded"] - start["padded"],
+            "flushed_requests": end["requests"] - start["requests"],
+            "queue_wait_s": end["queue_wait_s"] - start["queue_wait_s"],
             "kept": kept, "rows_idx": rows_idx,
             "t0_epoch": time.time() - (clock() - t0),
             "stalls_at_s": _stalls(due_off, (sent - due) * 1e3, STALL_MS),

@@ -17,6 +17,16 @@ as the configuration states.  ``control`` computes the same forward one
 step below one of the precisions the configuration states: its products
 as three bf16 passes (``high``) or one (``default``), or its activations
 on the int4 grid (``int4``).
+
+The family's costs, counted from the true layer shapes whatever schedule,
+padding or fallback the program ran (a kernel that pads K from 784 to 896
+does more work than the model needs, and the roofline share shows it):
+
+    ops(rows)   = 2 * rows * sum_l K_l * N_l
+    bytes(rows) = rows * K_0 * input bytes        (the batch read once)
+                + rows * N_L * 4                  (f32 logits written once)
+                + sum_l K_l * N_l / 2             (packed 4-bit codes)
+                + sum_l (8 * N_l + 20)            (alpha1, bias, omega, scale)
 """
 from __future__ import annotations
 
@@ -33,11 +43,28 @@ HIGHEST = jax.lax.Precision.HIGHEST
 # this share of the reference's largest logit: far above float32 rounding
 # (about 1e-7), far below one activation moved by one step of its grid
 MISS_TOL = 1e-4
+# the entries of ``plan.describe()`` that a run logs on its ``plan:`` line
+PLAN_KEYS = ("resolved_mode", "block_m", "bucket_schedules",
+             "bucket_block_m", "default_path", "interpret")
 
 
 def shapes(config: dict) -> List[tuple]:
+    """(K, N) of each layer."""
     widths = [int(config["d_in"])] + [int(n) for n in config["features"]]
     return list(zip(widths[:-1], widths[1:]))
+
+
+def ops(shapes: Sequence[tuple], rows: int) -> float:
+    return 2.0 * rows * sum(k * n for k, n in shapes)
+
+
+def bytes_moved(shapes: Sequence[tuple], rows: int,
+                input_bytes: int = 4) -> float:
+    k0, n_last = shapes[0][0], shapes[-1][1]
+    weights = sum(k * n / 2 for k, n in shapes)
+    epilogue = sum(8 * n + 20 for _, n in shapes)
+    return float(rows * k0 * input_bytes + rows * n_last * 4 + weights
+                 + epilogue)
 
 
 def seed_key(seed: int, stream: int) -> jax.Array:
@@ -78,6 +105,7 @@ class Model:
             raise ValueError("odd K needs a padded code row; not made here")
         self.act_dtype = config["act_dtype"]
         self.d_in = self.shapes[0][0]
+        self.d_out = self.shapes[-1][1]
         arrays = _make_layers(seed_key(seed, 0), tuple(self.shapes))
         n = len(self.shapes)
         self.layers = [
@@ -90,6 +118,10 @@ class Model:
             x = jax.random.normal(seed_key(seed, 2), (CALIB_ROWS, self.d_in),
                                   jnp.float32)
             self.act_scales = calibrate(self.layers, x)
+
+    def device_arrays(self) -> list:
+        """The arrays set-up waits on before it times the plan build."""
+        return [l["packed"] for l in self.layers]
 
     def pack(self) -> dict:
         """The serving pack handed to the program."""

@@ -51,8 +51,7 @@ def test_control_fails_the_cells_limit(cell):
     cfg = loader.config(wl["config"])
     run = bench.Run(bench.parse(["--workload", cell, "--seed", "3",
                                  "--seconds", "1"]), wl, cfg, 0.0)
-    run.family = mlp
-    run.model = mlp.Model(cfg, 3)
+    run.model = run.family.Model(cfg, 3)
     x = np.random.default_rng(1).normal(size=(256, run.model.d_in)).astype(
         np.float32)
     pairs = [(x, run.model.reference(x))]

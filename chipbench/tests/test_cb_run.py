@@ -108,3 +108,31 @@ def test_sampled_rows_are_the_served_rows(capsys, monkeypatch):
     res = run_tiny("gsc-int8-online", capsys, seed=5, rate=2000)
     assert res["correct"] is False
     assert np.isfinite(res["checks"]["max_rel_err"]["value"])
+
+
+def test_offline_window_counts_stalled_calls():
+    """A ``plan.run`` call longer than ``STALL_S`` is counted as a stall,
+    and the window's summary line gives the count and the longest call."""
+    import time
+    import types
+    from harness import offline
+    calls = []
+
+    def plan_run(x):
+        calls.append(x)
+        if len(calls) == 3:
+            time.sleep(2 * offline.STALL_S)
+        return x
+    run = types.SimpleNamespace(params={"rows": 4},
+                                plan=types.SimpleNamespace(run=plan_run),
+                                rng=np.random.default_rng)
+    traffic = offline.Offline(run)
+    traffic.batches = [np.zeros((4, 2), np.float32)] * offline.POOL
+    w = traffic.window(0.3)
+    assert w["attempted"] == len(calls) > 3
+    assert w["stalls"] >= 1
+    assert w["longest_call_s"] >= 2 * offline.STALL_S
+    assert w["stall_s"] >= w["longest_call_s"]
+    line = traffic.summary(w)
+    assert (line["stalls"], line["longest_call_s"]) == (
+        w["stalls"], w["longest_call_s"])

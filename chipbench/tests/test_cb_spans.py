@@ -190,3 +190,67 @@ def test_frontend_launch_spans_nest_in_order(tmp_path, streams):
     assert len(meta) == flushes
     assert sum(m["requests"] for m in meta) == len(xs)
     assert all(m["bucket"] >= m["rows"] >= m["requests"] for m in meta)
+
+
+def _span_split():
+    import importlib.util
+    path = os.path.join(os.path.dirname(DATA), os.pardir, "tools",
+                        "span_split.py")
+    spec = importlib.util.spec_from_file_location("cb_span_split", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _synthetic_offline_trace():
+    """Two ``plan.run`` calls in the window, one after it."""
+    window = [ev(trace.WINDOW_SPAN, 0, 1000)]
+    host = [ev("serving.plan_run#decode=once#", 100, 300),
+            ev("serving.plan_run", 500, 900),
+            ev("serving.plan_run", 1000, 1100)]
+    dev = [ev("%k.1 = f32[8] custom-call(%a)", 150, 450)]
+    return trace.Trace({0: dev}, [window, host])
+
+
+# (source, reader) -> its reading, worked out by hand from the reduction's
+# totals and the window's counters; every other pair reads nothing
+PINNED = {
+    ("online-chip", "launch_host_ms.lat"): 0.298666279 / 159 * 1e3,
+    ("online-chip", "enqueue_ms.lat"): 0.042004824 / 159 * 1e3,
+    ("online-chip", "idle_in_launch.lat"): 0.292819243 / 0.297160293,
+    ("online-chip", "queue_wait_ms.lat"): 2.44 / 1920 * 1e3,
+    ("offline-synthetic", "plan_run_ms.tput"): 600e-9 / 2 * 1e3,
+}
+SPAN_METRICS = ("enqueue_ms.lat", "idle_in_launch.lat", "launch_host_ms.lat",
+                "plan_run_ms.tput", "queue_wait_ms.lat")
+
+
+@pytest.mark.parametrize("metric", SPAN_METRICS)
+@pytest.mark.parametrize("source", ["online-chip", "offline-synthetic"])
+def test_span_readers_agree_with_span_split(metric, source):
+    """Each reader of the program's spans and counters gives its pinned
+    reading, on the chip's online trace and on a synthetic offline one,
+    and ``tools/span_split.py`` reports what the reader gives."""
+    import types
+    from harness import loader
+    if source == "online-chip":
+        t = trace.load(os.path.join(DATA, "gsc-int8-online-spans.xplane.pb"))
+        window = {"launches": 159, "flushed_requests": 1920,
+                  "queue_wait_s": 2.44}
+    else:
+        t = _synthetic_offline_trace()
+        window = {"kernel_calls": 2, "kernel_rows": 16}
+    reduced = trace.reduce(t, devices=[0])
+    reduced["spans"] = spans.reduce(t, devices=[0])
+    r = types.SimpleNamespace(trace=reduced, window=window)
+    got = loader.metric_reader(metric).read(r)
+    expected = PINNED.get((source, metric))
+    if expected is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(expected, rel=1e-6)
+    report = _span_split().report(r, [metric])
+    assert report["metrics"] == {metric: got}
+    assert ("counters" in report) == (source == "online-chip")
+    untraced = types.SimpleNamespace(trace=None, window={})
+    assert loader.metric_reader(metric).read(untraced) is None

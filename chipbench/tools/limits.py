@@ -35,9 +35,10 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         print("limits: needs a TPU", file=sys.stderr)
         return 2
-    bench.use_bindings(tempfile.mkdtemp(prefix="chipbench-"))
     wl = loader.workload(args.workload)
     cfg = loader.config(wl["config"])
+    bench.use_bindings(tempfile.mkdtemp(prefix="chipbench-"),
+                       loader.bindings_path(cfg["name"]))
     controls = cfg["controls"]
     lower = {k: [] for k in bench.NUMBERS}
     upper = {c: {k: [] for k in bench.NUMBERS} for c in controls}
@@ -47,7 +48,6 @@ def main() -> int:
                                      "--seed", seed,
                                      "--seconds", args.seconds]),
                         wl, cfg, t)
-        run.family = loader.family(cfg["family"])
         run.device_kind = jax.devices()[0].device_kind
         run.model = run.family.Model(cfg, run.seed)
         run.plan = run.model.plan()

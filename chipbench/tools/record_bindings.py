@@ -1,21 +1,21 @@
 #!/usr/bin/env python3
-"""Record the block bindings of every configuration the cells use, by one
-sweep on the chip.
+"""Record one configuration's block bindings, by one sweep on the chip.
 
-    python chipbench/tools/record_bindings.py [--out FILE]
+    python chipbench/tools/record_bindings.py --config <name> [--out FILE]
 
-Builds each configuration's serving plan against an empty autotune file,
+Builds the configuration's serving plan against an empty autotune file,
 so the program sweeps (schedule, block_m) for every bucket on this chip,
-and writes the file to ``chipbench/bindings/autotune.json`` (and to
-``--out``).  Runs leave that file alone: set-up then runs no sweep.  A
-change that should move a binding is invisible until a benchmark change
-records them again.
+and writes the file to ``chipbench/bindings/<name>.json`` (and to
+``--out``).  Every other configuration's file is left as it is.  Runs
+leave the file alone: set-up then runs no sweep.  A change that should
+move a binding is invisible until a benchmark change records it again.
 """
 import argparse
 import json
 import os
 import shutil
 import sys
+import tempfile
 import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -24,39 +24,47 @@ sys.path[:0] = [HERE, os.path.join(os.path.dirname(HERE), "src")]
 from harness import loader  # noqa: E402
 
 
+def record(cfg: dict, target: str) -> dict:
+    """Build ``cfg``'s plan against an empty autotune file and, if no
+    candidate failed, write what the build bound to ``target``.  Returns
+    the plan's ``describe()`` entries that a run logs, and the failures."""
+    from repro.kernels import autotune
+    family = loader.family(cfg["family"])
+    with tempfile.TemporaryDirectory(prefix="chipbench-") as tmp:
+        path = os.path.join(tmp, "autotune.json")
+        os.environ[autotune.ENV_CACHE] = path
+        autotune.clear_memory_cache()
+        t = time.perf_counter()
+        with autotune.collect_failures([]) as failures:
+            plan = family.Model(cfg, 0).plan()
+        d = plan.describe()
+        line = {"config": cfg["name"], "seconds": time.perf_counter() - t,
+                "failures": failures,
+                **{k: d[k] for k in family.PLAN_KEYS}}
+        if not failures:
+            if not os.path.exists(path):     # the build bound nothing
+                with open(path, "w") as f:
+                    json.dump({}, f)
+            shutil.copyfile(path, target)
+    return line
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--config", required=True)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
     import jax
     if jax.default_backend() != "tpu":
         print("record_bindings: needs a TPU", file=sys.stderr)
         return 2
-    from repro.kernels import autotune
     from repro.launch import compile_cache
-    target = os.path.join(HERE, "bindings", "autotune.json")
-    path = os.path.join(HERE, "bindings", "autotune.recording.json")
-    if os.path.exists(path):
-        os.remove(path)
-    os.environ[autotune.ENV_CACHE] = path
-    autotune.clear_memory_cache()
     compile_cache.enable()
-    configs = sorted({w["config"] for w in loader.spec()["workloads"]})
-    for name in configs:
-        cfg = loader.config(name)
-        t = time.perf_counter()
-        with autotune.collect_failures([]) as failures:
-            plan = loader.family(cfg["family"]).Model(cfg, 0).plan()
-        d = plan.describe()
-        print(json.dumps({"config": name, "seconds":
-                          time.perf_counter() - t, "failures": failures,
-                          **{k: d[k] for k in (
-                              "block_m", "bucket_schedules",
-                              "bucket_block_m", "default_path")}}),
-              flush=True)
-        if failures:
-            return 1
-    shutil.move(path, target)
+    target = loader.bindings_path(args.config)
+    line = record(loader.config(args.config), target)
+    print(json.dumps(line), flush=True)
+    if line["failures"]:
+        return 1
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)

@@ -7,12 +7,13 @@ time by the program's ``serving.*`` spans.
 
 Prints ``run.py``'s own result line, then one JSON line: the cost of one
 span on this host with no trace active (plain, and with the metadata a
-launch sets); ``harness/spans.py``'s reduction of the window; the mean
-milliseconds of each span, the launch's self time (its duration less the
-union of its child spans) and the share the children cover; the share of
-the device's idle time inside a launch; and, for open-loop traffic, the
-micro-batcher's ``queue_wait_s`` and ``flushed_requests`` over the window
-and its drain, with their mean in ms.  ``--out`` keeps the trace.
+launch sets); the cell's per-layer metrics as the harness's readers give
+them; the harness's reduction of the window's spans (``harness/spans.py``,
+as the readers find it); what no reader gives: the mean milliseconds of
+every span, the launch's self time (its duration less the union of its
+child spans) and the share the children cover; and, for open-loop
+traffic, the micro-batcher's counters over the window.  ``--out`` keeps
+the trace.
 """
 import argparse
 import json
@@ -24,9 +25,9 @@ import time
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [HERE, os.path.join(os.path.dirname(HERE), "src")]
 
-from harness import bench, openloop, spans, trace  # noqa: E402
+from harness import bench, loader, spans  # noqa: E402
 
-COUNTERS = ("flushes", "flushed_requests", "queue_wait_s")
+COUNTERS = ("launches", "flushed_requests", "queue_wait_s")
 
 
 def span_cost_us(n: int = 200_000) -> dict:
@@ -45,6 +46,31 @@ def span_cost_us(n: int = 200_000) -> dict:
     return {"span_us": plain / n * 1e6, "span_metadata_us": meta / n * 1e6}
 
 
+def split(r: dict) -> dict:
+    """What no metric reader gives of one traced window's span reduction
+    ``r`` (``spans.reduce``)."""
+    out = {"spans": r,
+           "mean_ms": {k: spans.mean_ms(r, k) for k in r["spans"]}}
+    launch = r["spans"].get(spans.LAUNCH)
+    if launch:
+        out["launch_self_ms"] = (launch["s"] - r["launch_covered_s"]) \
+            / launch["n"] * 1e3
+        out["launch_covered"] = r["launch_covered_s"] / launch["s"]
+    return out
+
+
+def report(readings, names) -> dict:
+    """The metrics ``names`` as the harness's readers read ``readings``,
+    the batcher's counters, and the split of the window's spans."""
+    out = {"metrics": {n: loader.metric_reader(n).read(readings)
+                       for n in names}}
+    counters = {k: readings.window[k] for k in COUNTERS
+                if k in readings.window}
+    if counters:
+        out["counters"] = counters
+    return {**out, **split(readings.trace["spans"])}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -55,51 +81,21 @@ def main() -> int:
     cost = span_cost_us()
     kept = {}
 
-    # keep the trace bench.py loads, and the batcher's counters around
-    # the last (the measured) window
-    load = trace.load
-
-    def keep(path):
-        kept["trace"] = load(path)
+    def keep(readings):
+        names = [m["name"] for m in loader.metrics_for(
+            loader.spec(), args.workload, True)]
+        kept["split"] = report(readings, names)
         if args.out:
             os.makedirs(args.out, exist_ok=True)
-            shutil.copy(trace.find_xspace(path), args.out)
-        return kept["trace"]
+            shutil.copy(readings.xspace, args.out)
 
-    window = openloop.OpenLoop.window
-
-    def counted(self, *a, **k):
-        st = self.batcher.stats
-        before = {c: st.get(c, 0) for c in COUNTERS}
-        w = window(self, *a, **k)
-        kept["counters"] = {c: st.get(c, 0) - before[c] for c in COUNTERS}
-        return w
-
-    trace.load = keep
-    openloop.OpenLoop.window = counted
     rc = bench.execute(["--workload", args.workload, "--seed",
                         str(args.seed), "--seconds", str(args.seconds),
-                        "--trace", "1"])
-    if rc or "trace" not in kept:
+                        "--trace", "1"], on_readings=keep)
+    if rc or "split" not in kept:
         return rc or 1
-    r = spans.reduce(kept["trace"])
-    out = {"workload": args.workload, "seed": args.seed, "cost": cost,
-           "spans": r,
-           "mean_ms": {k: spans.mean_ms(r, k) for k in r["spans"]}}
-    launch = r["spans"].get(spans.LAUNCH)
-    if launch:
-        out["launch_self_ms"] = (launch["s"] - r["launch_covered_s"]) \
-            / launch["n"] * 1e3
-        out["launch_covered"] = r["launch_covered_s"] / launch["s"]
-    if sum(r["idle_s"]):
-        out["idle_in_launch"] = sum(r["idle_in_launch_s"]) / sum(r["idle_s"])
-    c = kept.get("counters")
-    if c:
-        out["counters"] = c
-        if c["flushed_requests"]:
-            out["queue_wait_ms"] = c["queue_wait_s"] \
-                / c["flushed_requests"] * 1e3
-    print(json.dumps(out), flush=True)
+    print(json.dumps({"workload": args.workload, "seed": args.seed,
+                      "cost": cost, **kept["split"]}), flush=True)
     return 0
 
 

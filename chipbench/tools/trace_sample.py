@@ -31,7 +31,8 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         print("trace_sample: needs a TPU", file=sys.stderr)
         return 2
-    bench.use_bindings(tempfile.mkdtemp(prefix="chipbench-"))
+    bench.use_bindings(tempfile.mkdtemp(prefix="chipbench-"),
+                       loader.bindings_path(args.config))
     cfg = loader.config(args.config)
     model = loader.family(cfg["family"]).Model(cfg, 0)
     plan = model.plan()
