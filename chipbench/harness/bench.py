@@ -7,7 +7,9 @@ A run loads ``workloads/<cell>.json`` and its ``configs/<config>.json``,
 makes the model from the seed (``models/<family>.py``), builds the plan
 with the configuration's committed block bindings
 (``bindings/<config>.json``; no sweep), lets ``traffic/<kind>.py``
-warm up exactly the shapes its traffic uses, measures for ``--seconds``,
+warm up exactly the shapes its traffic uses (a block bound by the plan
+build or the warm-up that the file lacks ends the run), measures for
+``--seconds``,
 checks what the timed path produced against the plain reference, and
 prints one JSON line.  With ``--trace 1`` the window runs under the
 profiler and the line carries the per-layer metrics instead of the
@@ -50,7 +52,8 @@ def parse(argv: Optional[List[str]]) -> argparse.Namespace:
 
 class Readings:
     """What the metric readers read: the window's numbers, the trace's
-    reduction (``trace``; its ``spans`` the program's own, as
+    reduction (``trace``: ``harness.trace.reduce``'s, with every device
+    op's seconds under ``op_s``, and the program's own ``spans``, as
     ``harness.spans.reduce`` gives them) and the trace file it came from,
     the set-up split, and the cell's model family, shapes and device."""
 
@@ -174,10 +177,25 @@ def execute(argv: Optional[List[str]] = None, *, require_chip: bool = True,
         shutil.rmtree(tmp, ignore_errors=True)
 
 
+def _bindings_held(run: Run, pinned: Optional[str], what: str) -> bool:
+    """Whether the autotuner's copy still equals the pinned bindings file
+    (always, for a run without one); if ``what`` bound a block the file
+    lacks, says which file and how to record it."""
+    from repro.kernels import autotune
+    if pinned is None:
+        return True
+    with open(pinned) as a, open(autotune.cache_path()) as b:
+        if json.load(a) == json.load(b):
+            return True
+    _log(f"chipbench: {what} swept block bindings that "
+         f"{os.path.relpath(pinned, loader.ROOT)} lacks; record them with "
+         f"{RECORD} {run.config['name']}")
+    return False
+
+
 def _run(run: Run, spec: dict, used, meter, tmp: str, pinned: Optional[str],
          on_readings: Optional[Callable[[Readings], None]]) -> int:
     import jax
-    from repro.kernels import autotune
     args = run.args
     traffic = loader.traffic(run.workload["traffic"]).Traffic(run)
 
@@ -188,15 +206,13 @@ def _run(run: Run, spec: dict, used, meter, tmp: str, pinned: Optional[str],
     t = run.mark("weights", t)
     run.plan = run.model.plan()
     t = run.mark("plan", t)
-    if pinned is not None:
-        with open(pinned) as a, open(autotune.cache_path()) as b:
-            if json.load(a) != json.load(b):
-                _log(f"chipbench: the plan build swept block bindings "
-                     f"that {os.path.relpath(pinned, loader.ROOT)} lacks; "
-                     f"record them with {RECORD} {run.config['name']}")
-                return 1
+    if not _bindings_held(run, pinned, "the plan build"):
+        return 1
     traffic.setup()
     t = run.mark("warmup", t)
+    if not _bindings_held(run, pinned, "the warm-up"):
+        traffic.close()
+        return 1
     # what set-up made lives as long as the process: keep it out of the
     # collector's full passes, which would otherwise stall the window
     gc.collect()

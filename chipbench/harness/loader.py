@@ -15,6 +15,36 @@ one per-layer metric lives in a file of its own, named after it:
 and ``BENCHMARK.json`` at the root of the checkout says which metrics each
 cell reports.  Adding a configuration, a model family, a cell or a metric
 adds files and entries; nothing here changes.
+
+A configuration names its ``family``, its ``source`` and its ``reduced``
+keys.  Where ``reduced`` is not empty, it also carries ``published`` (each
+reduced key with its published value) and ``deployment`` (how many chips
+share a layer, and how).
+
+A model family (``models/<family>.py``) provides:
+
+    shapes(config)                     the shapes its costs are counted from
+    ops(shapes, rows)                  operations of ``rows`` rows
+    bytes_moved(shapes, rows, input_bytes)
+                                       bytes one call of ``rows`` rows moves
+    seed_key(seed, stream), seed_rng(seed, stream)
+                                       the run's random streams
+    max_rel_err(served, ref), miss_count(served, ref)
+                                       the numbers compared
+    PLAN_KEYS                          the ``describe()`` entries of the
+                                       ``plan:`` log line
+    Model(config, seed)                with ``d_out``, ``device_arrays()``,
+                                       ``plan()``, ``reference(x)`` and
+                                       ``control(x, kind)``; ``d_in`` where
+                                       the traffic kind makes float rows
+                                       (``online``, ``offline``)
+    control_model(config, seed)        the model whose controls the CPU test
+                                       puts in the program's place: the
+                                       published widths, and at least one
+                                       whole period of the layer pattern
+    inputs(model, rng, rows)           a batch of ``rows`` rows such a model
+                                       takes (feature rows, token ids), drawn
+                                       from the numpy generator ``rng``
 """
 from __future__ import annotations
 

@@ -7,6 +7,8 @@ The window is the benchmark's own host span (``WINDOW_SPAN``, a
   (line ``XLA Ops`` of each ``/device:TPU:<n>`` plane), per device;
 - kernel time: the summed durations of the Pallas kernels' events, which
   the TPU runtime records as custom calls (``is_kernel``);
+- op time: the seconds of every device operation, by name (``op_name``),
+  summed over the devices;
 - breakdown: the device operations that took most time, and the longest
   idle gaps on each device, named by the innermost host span that covers
   the gap's midpoint.
@@ -150,8 +152,9 @@ def host_label(trace: Trace, t: float) -> str:
 
 
 def reduce(trace: Trace, devices: Optional[Sequence[int]] = None) -> dict:
-    """Busy and kernel seconds per device over the window, their means,
-    and the breakdown (at most ``TOP`` entries in each list)."""
+    """Busy and kernel seconds per device over the window, the seconds of
+    every op by name summed over the devices (``op_s``), and the breakdown
+    (at most ``TOP`` entries in each list)."""
     lo, hi = trace.window()
     ids = sorted(trace.devices) if devices is None else list(devices)
     if not ids:
@@ -179,6 +182,7 @@ def reduce(trace: Trace, devices: Optional[Sequence[int]] = None) -> dict:
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy_s,
         "kernel_s": kernel_s,
+        "op_s": dict(per_op),
         "breakdown": {
             "device_ops": [[n, s] for n, s in per_op.most_common(TOP)],
             "idle_gaps": idle,

@@ -77,6 +77,18 @@ def seed_rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([seed % 2 ** 64, stream])
 
 
+def control_model(config: dict, seed: int) -> "Model":
+    """The model whose controls the CPU test puts in the program's place:
+    the configuration's own, since every MLP is small enough there."""
+    return Model(config, seed)
+
+
+def inputs(model: "Model", rng: np.random.Generator, rows: int
+           ) -> np.ndarray:
+    """A batch of ``rows`` standard-normal float32 feature rows."""
+    return rng.normal(size=(rows, model.d_in)).astype(np.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("layer_shapes",))
 def _make_layers(key, layer_shapes):
     """Every layer's arrays in one program: uniform 4-bit codes, a

@@ -40,20 +40,30 @@ def test_model_is_made_from_the_seed():
     assert a.layers[0]["packed"].dtype == np.uint8
 
 
+CONTROL_SEED, CONTROL_ROWS = 3, 256
+# sha256 of the rows the control test has compared since it was written,
+# ``default_rng(1).normal(size=(256, d_in))`` as float32, by d_in
+CONTROL_ROWS_SHA256 = {
+    512: "d40776736029074c8a60b72d4c820575ae6344644d628f16d5d4e30d73641d2a",
+    784: "eda78aae89fa0241a1818473b9ca14d716aa68ba3bd9ce098271e6850a133244",
+}
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in loader.spec()[
     "workloads"]])
 def test_control_fails_the_cells_limit(cell):
     """Each control of the configuration (the reference one step below a
     precision it states: int4 activations, products in three bf16 passes
     or one), put in the program's place, misses one of the cell's limits
-    at the configuration's own widths."""
+    on the family's control model (``control_model``: the published
+    widths) and a batch of its inputs."""
     wl = loader.workload(cell)
     cfg = loader.config(wl["config"])
-    run = bench.Run(bench.parse(["--workload", cell, "--seed", "3",
-                                 "--seconds", "1"]), wl, cfg, 0.0)
-    run.model = run.family.Model(cfg, 3)
-    x = np.random.default_rng(1).normal(size=(256, run.model.d_in)).astype(
-        np.float32)
+    run = bench.Run(bench.parse(["--workload", cell, "--seed",
+                                 str(CONTROL_SEED), "--seconds", "1"]),
+                    wl, cfg, 0.0)
+    run.model = run.family.control_model(cfg, CONTROL_SEED)
+    x = run.family.inputs(run.model, np.random.default_rng(1), CONTROL_ROWS)
     pairs = [(x, run.model.reference(x))]
     assert all(v == 0 for v in bench.compare(run, pairs).values())
     assert cfg["controls"]
@@ -61,6 +71,25 @@ def test_control_fails_the_cells_limit(cell):
         found = bench.compare(run, pairs, control=control)
         assert any(found[k] > lim for k, lim in wl["limits"].items()), \
             (control, found)
+
+
+@pytest.mark.parametrize("name", ["mlp-gsc-int8", "lenet-300-100-fp32"])
+def test_mlp_control_case_is_unchanged(name):
+    """``mlp4bit.control_model`` and ``inputs`` give the model and the rows
+    the control test built itself before the family provided them."""
+    import hashlib
+    cfg = loader.config(name)
+    model = mlp.control_model(cfg, CONTROL_SEED)
+    old = mlp.Model(cfg, CONTROL_SEED)
+    assert type(model) is mlp.Model and model.shapes == old.shapes
+    for a, b in zip(model.layers, old.layers):
+        for k in ("packed", "omega", "alpha1", "bias", "alpha2"):
+            assert np.array_equal(a[k], b[k]), k
+    assert model.act_scales == old.act_scales
+    x = mlp.inputs(model, np.random.default_rng(1), CONTROL_ROWS)
+    assert x.shape == (CONTROL_ROWS, model.d_in) and x.dtype == np.float32
+    assert hashlib.sha256(x.tobytes()).hexdigest() == \
+        CONTROL_ROWS_SHA256[model.d_in]
 
 
 def test_miss_count_counts_rows():

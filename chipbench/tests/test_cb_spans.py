@@ -84,7 +84,9 @@ def test_trace_reduce_unchanged_on_the_recorded_chip_trace():
     and splits the idle time alone."""
     t = trace.load(os.path.join(DATA, "gsc-int8-8rows.xplane.pb"))
     r = trace.reduce(t)
-    assert sorted(r) == ["breakdown", "busy_s", "kernel_s", "window_s"]
+    assert sorted(r) == ["breakdown", "busy_s", "kernel_s", "op_s",
+                         "window_s"]
+    assert r["op_s"] == dict(r["breakdown"]["device_ops"])
     assert r["window_s"] == pytest.approx(0.004151359)
     assert r["busy_s"] == [pytest.approx(7.3493e-05)]
     assert r["kernel_s"] == [pytest.approx(7.0878e-05)]

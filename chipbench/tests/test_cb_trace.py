@@ -41,6 +41,7 @@ def test_reduce_synthetic():
     ops = dict(r["breakdown"]["device_ops"])
     assert ops["fantastic4_fused_mlp_pallas"] == pytest.approx(300e-9)
     assert ops["copy"] == pytest.approx(200e-9)
+    assert r["op_s"] == ops
 
 
 def test_reduce_needs_the_window_span():
@@ -64,3 +65,43 @@ def test_reduce_a_trace_recorded_on_the_chip():
     ops = dict(r["breakdown"]["device_ops"])
     assert ops["fantastic4_fused_mlp_stream_pallas"] == \
         pytest.approx(7.0878e-05)
+
+
+def test_op_s_is_not_cut_at_top():
+    """Two devices, ``TOP`` + 2 op names: ``op_s`` keeps them all, each
+    summed over both devices and clipped to the window, and the breakdown
+    still lists the ``TOP`` largest."""
+    host = [[ev(trace.WINDOW_SPAN, 0, 10_000)]]
+    names = [f"%op{i}.{i} = f32[8] fusion(f32[8] %a)" for i in
+             range(trace.TOP + 2)]
+    dev = {d: [ev(n, 100 * i, 100 * i + 10 * (i + 1) * (d + 1))
+               for i, n in enumerate(names)] for d in (0, 1)}
+    dev[0].append(ev(names[0], 9_990, 10_050))
+    r = trace.reduce(trace.Trace(dev, host))
+    assert len(r["op_s"]) == trace.TOP + 2
+    for i in range(trace.TOP + 2):
+        want = 10 * (i + 1) * 3 + (10 if i == 0 else 0)
+        assert r["op_s"][f"op{i}"] == pytest.approx(want * 1e-9)
+    top = r["breakdown"]["device_ops"]
+    assert [n for n, _ in top] == [f"op{i}" for i in
+                                   range(trace.TOP + 1, 1, -1)]
+    assert all(r["op_s"][n] == s for n, s in top)
+
+
+def test_op_s_on_a_trace_recorded_on_the_chip():
+    """On an online chip trace (MLP-GSC int8, 1-128 row buckets) ``op_s``
+    holds every op of the breakdown with the same seconds, and the Pallas
+    kernels' ops add up to ``kernel_s``."""
+    t = trace.load(os.path.join(DATA, "gsc-int8-online-spans.xplane.pb"))
+    r = trace.reduce(t)
+    ops = r["breakdown"]["device_ops"]
+    assert ops
+    for name, seconds in ops:
+        assert r["op_s"][name] == seconds
+    kernels = {trace.op_name(e.name) for evs in t.devices.values()
+               for e in evs if trace.is_kernel(e)}
+    assert kernels and not kernels & {
+        trace.op_name(e.name) for evs in t.devices.values() for e in evs
+        if not trace.is_kernel(e)}
+    assert sum(r["op_s"][k] for k in kernels) == pytest.approx(
+        sum(r["kernel_s"]), rel=1e-12)

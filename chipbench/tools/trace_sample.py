@@ -34,11 +34,12 @@ def main() -> int:
     bench.use_bindings(tempfile.mkdtemp(prefix="chipbench-"),
                        loader.bindings_path(args.config))
     cfg = loader.config(args.config)
-    model = loader.family(cfg["family"]).Model(cfg, 0)
+    family = loader.family(cfg["family"])
+    model = family.Model(cfg, 0)
     plan = model.plan()
-    # rows made on the host, row-major, as a user's rows arrive
-    x = jax.device_put(np.random.default_rng(1).standard_normal(
-        (args.rows, model.d_in), dtype=np.float32))
+    # a batch made on the host, as a user's batch arrives
+    x = jax.device_put(family.inputs(model, np.random.default_rng(1),
+                                     args.rows))
     np.asarray(plan.run(x))
     jax.profiler.start_trace(args.out,
                              profiler_options=bench._trace_options())
